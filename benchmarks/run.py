@@ -44,6 +44,9 @@ def main(argv: list[str] | None = None) -> None:
                     help="write BENCH_<section>.json files into DIR")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_chaos,
         bench_ese_estimates,

@@ -1,5 +1,3 @@
 """repro — systems reproduction of "System Support for Environmentally
 Sustainable Computing in Data Centers" (FRAC storage codec, carbon-aware
 training, ESE estimator, Amoeba engines) on jax/Pallas."""
-
-from repro import compat as _compat  # noqa: F401  (jax API backports)

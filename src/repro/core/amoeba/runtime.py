@@ -83,8 +83,13 @@ def run_primitive(job: PrimitiveJob) -> PrimitiveResult:
     train job runs on.  Deterministic per (workload, size, seed): the
     checksum witnesses that a dispatched job computed the same result
     wherever the controller scheduled it."""
+    import jax
+
     pes = engines.dispatch(job.workload)
     rng = np.random.default_rng(job.seed)
+    # the Pallas kernels compile for a TPU; any other backend can only
+    # run them through the interpreter
+    interpret = jax.default_backend() != "tpu"
     t0 = time.perf_counter()
     if job.workload == "ntt":
         from repro.kernels.ntt import ops as ntt_ops
@@ -92,14 +97,14 @@ def run_primitive(job: PrimitiveJob) -> PrimitiveResult:
         n = 1 << max(int(np.log2(max(job.size, 2))), 1)
         a = rng.integers(0, ntt_ref.Q, (2, n)).astype(np.int32)
         b = rng.integers(0, ntt_ref.Q, (2, n)).astype(np.int32)
-        out = np.asarray(ntt_ops.negacyclic_mul(a, b))
+        out = np.asarray(ntt_ops.negacyclic_mul(a, b, interpret=interpret))
         work = float(2 * n * max(np.log2(n), 1.0))
         digest = zlib.crc32(out.tobytes())
     elif job.workload == "sha3":
         from repro.kernels.sha3 import ops as sha3_ops
         msgs = [rng.integers(0, 256, 64).astype(np.uint8).tobytes()
                 for _ in range(job.size)]
-        digests = sha3_ops.sha3_256(msgs)
+        digests = sha3_ops.sha3_256(msgs, interpret=interpret)
         work = float(sum(len(m) for m in msgs))
         digest = zlib.crc32(b"".join(digests))
     else:                                   # "conv": pure MPE MVM

@@ -55,8 +55,8 @@ def operational_step_energy(roofline: RooflineRecord,
     u_c = roofline.t_compute_s / t
     u_m = roofline.t_memory_s / t
     u_i = roofline.t_collective_s / t
-    dyn = (hw.CHIP_TDP_W - hw.CHIP_IDLE_W)
-    chip_w = hw.CHIP_IDLE_W + dyn * (W_COMPUTE * u_c + W_MEMORY * u_m + W_ICI * u_i)
+    dyn = (hw.V5E.tdp_w - hw.V5E.idle_w)
+    chip_w = hw.V5E.idle_w + dyn * (W_COMPUTE * u_c + W_MEMORY * u_m + W_ICI * u_i)
     total_w = (chip_w + hw.HOST_OVERHEAD_W) * chips
     total_w *= (1.0 + DELIVERY_LOSS) * hw.PUE
     return StepEnergy(

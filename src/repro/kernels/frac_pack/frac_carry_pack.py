@@ -103,7 +103,7 @@ def _pad_rows(a: jax.Array, rows: int) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("k", "interpret"))
-def pack_carry(codes: jax.Array, k: int, interpret: bool = True) -> jax.Array:
+def pack_carry(codes: jax.Array, k: int, interpret: bool = False) -> jax.Array:
     """codes: (N,) uint32 < 2^k -> packed (ceil(N·k/32),) uint32, any
     k in 1..16.  Bit-identical to ``codec.pack_bits``."""
     assert k in SUPPORTED_K, f"pack_carry needs 1 <= k <= 16, got {k}"
@@ -127,7 +127,7 @@ def pack_carry(codes: jax.Array, k: int, interpret: bool = True) -> jax.Array:
 
 @partial(jax.jit, static_argnames=("k", "n", "interpret"))
 def unpack_carry(words: jax.Array, k: int, n: int,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """Inverse of pack_carry -> (n,) uint32."""
     assert k in SUPPORTED_K, f"unpack_carry needs 1 <= k <= 16, got {k}"
     c_seg, w_seg = seg_geometry(k)

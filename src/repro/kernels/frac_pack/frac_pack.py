@@ -46,7 +46,7 @@ def _unpack_kernel(words_ref, o_ref, *, k: int):
 
 
 @partial(jax.jit, static_argnames=("k", "interpret"))
-def pack32(codes: jax.Array, k: int, interpret: bool = True) -> jax.Array:
+def pack32(codes: jax.Array, k: int, interpret: bool = False) -> jax.Array:
     """codes: (N,) uint32 < 2^k, with (32/k) | N -> (N·k/32,) uint32."""
     assert 32 % k == 0, f"pack32 needs k | 32, got {k}"
     c = 32 // k
@@ -67,7 +67,7 @@ def pack32(codes: jax.Array, k: int, interpret: bool = True) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("k", "n", "interpret"))
-def unpack32(words: jax.Array, k: int, n: int, interpret: bool = True) -> jax.Array:
+def unpack32(words: jax.Array, k: int, n: int, interpret: bool = False) -> jax.Array:
     """Inverse of pack32 -> (n,) uint32."""
     assert 32 % k == 0
     c = 32 // k

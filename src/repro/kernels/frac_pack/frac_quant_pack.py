@@ -14,22 +14,26 @@ the checkpoint / grad-compress / KV-cache paths are built around
 (GreenFPGA's reconfigurable-primitive argument; Chasing Carbon's
 "don't let overhead eat the operational savings").
 
-Layout trick: the flat tensor is reshaped host-side (free, row-major)
-to ``(n_blocks, segments_per_block, codes_per_segment)`` so that the
-in-kernel pack is a static shift-OR over the *last* axis only — no
-in-kernel reshape, no strided lane access, no scatter.  A segment is
-one LCM(k, 32)-bit period of the packed stream: ``c_seg = 32/gcd(k,32)``
-codes in exactly ``w_seg = k/gcd(k,32)`` words, word-aligned and
-self-contained (see ``frac_carry_pack.py`` for the layout writeup).
-Code ``[b, s, j]`` is flat element ``b·256 + s·c_seg + j`` and lands in
-output word ``b·8k + s·w_seg + (j·k)//32`` at offset ``(j·k) % 32`` —
-exactly ``codec.pack_bits`` order, so the emitted words are
-bit-identical to the ``core/frac/codec.py`` oracle.  For word-aligned
-k the segment degenerates to w_seg = 1 and this is the PR-1 layout
-unchanged; for fractional k (the 11-bits-in-7-cells cell codes) the
-per-segment carry table from ``codec.seg_layout`` splits straddling
-codes into a lo shift into their start word plus a hi spill into the
-next, both OR-ed in statically.
+Layout: a segment is one LCM(k, 32)-bit period of the packed stream:
+``c_seg = 32/gcd(k,32)`` codes in exactly ``w_seg = k/gcd(k,32)``
+words, word-aligned and self-contained (see ``frac_carry_pack.py``).
+A 256-element block is ``S = 256/c_seg`` segments.  The wrapper
+transposes the flat tensor to ``(256, n_blocks)`` — blocks on the
+128-wide lane axis, row ``s·c_seg + j`` holding code ``j`` of segment
+``s`` — so every in-kernel step is lane-dense: the block absmax is a
+sublane reduction, code ``j`` of every segment is the stride-``c_seg``
+row slice from row ``j``, and word ``w`` of every segment is a static
+shift-OR of those slices written to the stride-``w_seg`` rows from
+``w`` of a ``(8k, n_blocks)`` output that the wrapper transposes back.
+The transposes keep the flat stream's own row order, so the wrapper's
+intermediates stay the size of the tensor.  Code ``[b, s, j]`` is flat element
+``b·256 + s·c_seg + j`` and lands in word ``b·8k + s·w_seg + (j·k)//32``
+at offset ``(j·k) % 32`` — exactly ``codec.pack_bits`` order, so the
+emitted words are bit-identical to the ``core/frac/codec.py`` oracle.
+For fractional k (the 11-bits-in-7-cells cell codes) the per-segment
+carry table from ``codec.seg_layout`` splits straddling codes into a
+lo shift into their start word plus a hi spill into the next, both
+OR-ed in statically.
 
 Supported k: every width 1–16 (fractional widths included — this is
 what puts the whole ``bits_for(m, α)`` degradation ladder on the fused
@@ -59,10 +63,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.frac.codec import BLOCK, seg_geometry, seg_layout
 
-TILE_BLOCKS = 32          # 256-element blocks per grid cell (32 KiB fp32 in)
+TILE_BLOCKS = 128         # 256-element blocks per grid cell (lanes);
+                          # fewer blocks make one full-width tile
 
 SUPPORTED_K = tuple(range(1, 17))
 
@@ -82,44 +88,125 @@ def block_layout(k: int) -> tuple[int, int, int]:
 
 
 # ---------------------------------------------------------------------------
-# kernels
+# correctly rounded division
+#
+# The codec quantizes ``x / scale`` with IEEE division, but f32
+# division on a TPU v5e is not correctly rounded: on 16M normal values
+# a third of the quotients came out one ulp off and 0.3% two ulps off,
+# flipping about one code in a million at a rounding boundary (the jnp
+# codec under XLA on the chip is off the same way).  ``div_rn``
+# repairs a quotient with exact float arithmetic only (IEEE
+# multiply/add, no FMA): Dekker's product gives ``x - r·s`` exactly,
+# and Shewchuk's expansion sum decides its sign against each half-ulp
+# midpoint.
 # ---------------------------------------------------------------------------
 
 
-def _encode_kernel(x_ref, o_words_ref, o_scales_ref, *, k: int,
-                   u_ref=None):
+def _two_sum(a, b):
+    """a + b == s + e exactly."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _split(a):
+    """Veltkamp split: a == hi + lo, each with at most 12 significant
+    bits, so products of halves are exact."""
+    t = 4097.0 * a
+    hi = t - (t - a)
+    return hi, a - hi
+
+
+def _sign3(a, b, c):
+    """Sign of a + b + c (b's magnitude below a's ulp, as _two_sum
+    leaves it), exact: the nonoverlapping expansion of the sum has the
+    sign of its largest nonzero component."""
+    q, h0 = _two_sum(c, b)
+    q, h1 = _two_sum(q, a)
+    top = jnp.where(q != 0, q, jnp.where(h1 != 0, h1, h0))
+    return jnp.where(top > 0, 1, jnp.where(top < 0, -1, 0))
+
+
+def div_rn(x, s, r):
+    """IEEE round-to-nearest-even ``x / s`` for ``s > 0``, given ``r``
+    within one ulp of it; from farther away, ``r`` moves one ulp toward
+    it.  Exact while ``s < 2^100`` (no overflow in the split) and away
+    from the subnormal range; there the quotient is either tiny against
+    the codec's ``+ 1`` or ``r`` is kept."""
+    ar = jnp.abs(r)
+    bits = jax.lax.bitcast_convert_type(ar, jnp.int32)
+    up = jax.lax.bitcast_convert_type(bits + 1, jnp.float32)
+    dn = jax.lax.bitcast_convert_type(jnp.maximum(bits - 1, 0), jnp.float32)
+    p = ar * s
+    rh, rl = _split(ar)
+    sh, sl = _split(s)
+    pe = ((rh * sh - p) + rh * sl + rl * sh) + rl * sl  # ar·s == p + pe
+    a, b = _two_sum(jnp.abs(x) - p, -pe)                # |x| - ar·s == a + b
+    # the true quotient lies above the midpoint to ``up`` (or on it,
+    # with ``up`` even), or below the midpoint to ``dn``
+    above = _sign3(a, b, -s * (0.5 * (up - ar)))
+    below = _sign3(a, b, s * (0.5 * (ar - dn)))
+    odd = (bits & 1) == 1                   # ties go to the even neighbour
+    go_up = (above > 0) | ((above == 0) & odd)
+    go_dn = (ar > 0) & ((below < 0) | ((below == 0) & odd))
+    fixed = jnp.where(go_up, up, jnp.where(go_dn, dn, ar))
+    fixed = jnp.where(s < 2.0 ** 100, fixed, ar)
+    return jnp.where(x < 0, -fixed, fixed)
+
+
+# ---------------------------------------------------------------------------
+# kernels (tiles are (rows, TILE_BLOCKS): one 256-element block per lane)
+# ---------------------------------------------------------------------------
+
+
+def _encode_kernel(*refs, k: int, stochastic: bool, interpret: bool):
     """One pass: absmax scale → quantize → carry-table shift-OR pack.
 
-    x tile: (TB, S, c_seg) fp32; words out: (TB, S, w_seg) uint32;
-    scales out: (TB, 1) fp32.  The last axis is the pack axis; the
-    static ``seg_layout`` table splits boundary-straddling codes into
-    lo/hi contributions (w_seg == 1 for aligned k: no straddlers)."""
+    x tile (and the stochastic uniforms): (256, TB) fp32, row
+    s·c_seg + j = code j of segment s; words out: (8k, TB) uint32, row
+    s·w_seg + w = word w of segment s; scales out: (1, TB) fp32.  The
+    whole tile quantizes at once into the codes scratch, from which
+    code j of every segment is the stride-c_seg row slice starting at
+    row j (word w is written the same way).  The static ``seg_layout``
+    table splits boundary-straddling codes into lo/hi contributions
+    (w_seg == 1 for aligned k: no straddlers)."""
+    if stochastic:
+        x_ref, u_ref, o_words_ref, o_scales_ref, codes_ref = refs
+    else:
+        x_ref, o_words_ref, o_scales_ref, codes_ref = refs
     q = (1 << k) - 1
-    _, _, w_seg = block_layout(k)
+    S, c_seg, w_seg = block_layout(k)
     _, _, _, contrib = seg_layout(k)
     x = x_ref[...]
-    scale = jnp.max(jnp.abs(x), axis=(1, 2), keepdims=True) + 1e-12
-    t = (x / scale + 1.0) * (0.5 * q)
-    if u_ref is not None:
+    scale = jnp.max(jnp.abs(x), axis=0, keepdims=True) + 1e-12
+    r = x / scale
+    if not interpret:                   # the interpreter divides exactly
+        # two steps: the chip's quotient can be two ulps off
+        r = div_rn(x, scale, div_rn(x, scale, r))
+    t = (r + 1.0) * (0.5 * q)
+    if stochastic:
         # stochastic rounding, same FMA-immune form as
-        # codec.quantize_blocks: floor(t) + (frac(t) + u >= 1)
-        t = jax.lax.optimization_barrier(t)
+        # codec.quantize_blocks: floor(t) + (frac(t) + u >= 1).  The
+        # interpreter runs on XLA, which would contract t's multiply
+        # into the subtraction below; Mosaic has no such barrier (and no
+        # fp32 FMA on the VPU)
+        if interpret:
+            t = jax.lax.optimization_barrier(t)
         tf = jnp.floor(t)
-        bump = (t - tf) + u_ref[...] >= 1.0
-        t = tf + bump.astype(jnp.float32)
+        t = tf + ((t - tf) + u_ref[...] >= 1.0).astype(jnp.float32)
     else:
         t = jnp.round(t)
-    codes = jnp.clip(t, 0, q).astype(jnp.uint32)
-    cols = []
+    # via int32: codes < 2^16 fit, and Mosaic has no direct float32 <->
+    # uint32 conversion
+    codes_ref[...] = jnp.clip(t, 0, q).astype(jnp.int32).astype(jnp.uint32)
     for w in range(w_seg):                   # disjoint bit ranges: or-accumulate
         acc = None
-        for j, s, is_hi in contrib[w]:
-            term = (codes[:, :, j] >> jnp.uint32(s)) if is_hi \
-                else (codes[:, :, j] << jnp.uint32(s))
+        for j, sh, is_hi in contrib[w]:
+            c = codes_ref[pl.ds(j, S, stride=c_seg), :]
+            term = (c >> jnp.uint32(sh)) if is_hi else (c << jnp.uint32(sh))
             acc = term if acc is None else acc | term
-        cols.append(acc)
-    o_words_ref[...] = jnp.stack(cols, axis=-1)
-    o_scales_ref[...] = scale[:, 0, :]
+        o_words_ref[pl.ds(w, S, stride=w_seg), :] = acc
+    o_scales_ref[...] = scale
 
 
 def _decode_kernel(words_ref, scales_ref, o_ref, *, k: int):
@@ -127,22 +214,20 @@ def _decode_kernel(words_ref, scales_ref, o_ref, *, k: int):
     scale.  Straddling codes OR their start word's high bits with the
     next word's low bits (the inverse carry)."""
     q = (1 << k) - 1
-    _, c_seg, _ = block_layout(k)
+    S, c_seg, w_seg = block_layout(k)
     w0, shift, spill, _ = seg_layout(k)
     mask = jnp.uint32(q)
-    w = words_ref[...]                       # (TB, S, w_seg) uint32
-    cols = []
-    for j in range(c_seg):
-        v = w[:, :, w0[j]] >> jnp.uint32(shift[j])
-        if spill[j]:
-            v = v | (w[:, :, w0[j] + 1] << jnp.uint32(32 - shift[j]))
-        cols.append((v & mask).astype(jnp.float32))
-    codes = jnp.stack(cols, axis=-1)         # (TB, S, c_seg)
-    scale = scales_ref[...]                  # (TB, 1)
     # same fusion-immune form as codec.dequantize_blocks (bit-exact):
     # exact integer 2c - q, constant fp32 reciprocal, plain multiplies
     inv_q = float(np.float32(1.0) / np.float32(q))
-    o_ref[...] = (codes * 2.0 - q) * (scale[:, :, None] * inv_q)
+    sc = scales_ref[...] * inv_q             # (1, TB)
+    for j in range(c_seg):
+        v = words_ref[pl.ds(w0[j], S, stride=w_seg), :] >> jnp.uint32(shift[j])
+        if spill[j]:
+            hi = words_ref[pl.ds(w0[j] + 1, S, stride=w_seg), :]
+            v = v | (hi << jnp.uint32(32 - shift[j]))
+        c = (v & mask).astype(jnp.int32).astype(jnp.float32)
+        o_ref[pl.ds(j, S, stride=c_seg), :] = (c * 2.0 - q) * sc
 
 
 # ---------------------------------------------------------------------------
@@ -150,49 +235,59 @@ def _decode_kernel(words_ref, scales_ref, o_ref, *, k: int):
 # ---------------------------------------------------------------------------
 
 
-def _pad_blocks(a: jax.Array, n_blocks: int, grid_blocks: int) -> jax.Array:
-    """Pad the leading (block) axis out to the grid's tile multiple."""
-    extra = grid_blocks - n_blocks
-    if extra:
-        a = jnp.pad(a, ((0, extra),) + ((0, 0),) * (a.ndim - 1))
-    return a
+def _to_lanes(a: jax.Array, width: int, gb: int) -> jax.Array:
+    """Flat block-major stream -> (width, gb): one block per lane, the
+    block axis zero-padded out to the grid."""
+    nb = a.size // width
+    a = a.reshape(nb, width).T
+    return jnp.pad(a, ((0, 0), (0, gb - nb))) if gb > nb else a
+
+
+def _from_lanes(a: jax.Array, nb: int) -> jax.Array:
+    """Inverse of ``_to_lanes`` -> flat, block-major, unpadded."""
+    return a[:, :nb].T.reshape(-1)
+
+
+def _grid(nb: int) -> tuple[int, int]:
+    """(blocks per tile, tiles): a tensor of fewer than TILE_BLOCKS
+    blocks is one tile as wide as itself (a block equal to the array's
+    extent meets the lane tiling rule), so small tensors are not padded
+    out to 128 blocks."""
+    tb = max(1, min(nb, TILE_BLOCKS))
+    return tb, pl.cdiv(nb, tb)
+
+
+def _tiles(rows: int, tb: int):
+    return pl.BlockSpec((rows, tb), lambda i: (0, i))
 
 
 @partial(jax.jit, static_argnames=("k", "stochastic", "interpret"))
-def _quant_pack_call(x3, u3, k: int, stochastic: bool, interpret: bool):
-    nb = x3.shape[0]
-    grid = pl.cdiv(nb, TILE_BLOCKS)
-    gb = grid * TILE_BLOCKS
-    S, c_seg, w_seg = block_layout(k)
-    x3 = _pad_blocks(x3, nb, gb)
-    kern = partial(_encode_kernel, k=k)
-    in_specs = [pl.BlockSpec((TILE_BLOCKS, S, c_seg), lambda i: (i, 0, 0))]
-    args = [x3]
+def _quant_pack_call(flat, u, k: int, stochastic: bool, interpret: bool):
+    nb = flat.shape[0] // BLOCK
+    tb, grid = _grid(nb)
+    gb = grid * tb
+    wpb = words_per_block(k)
+    args = [_to_lanes(flat, BLOCK, gb)]
     if stochastic:
-        kern = lambda x_ref, u_ref, ow, os: _encode_kernel(  # noqa: E731
-            x_ref, ow, os, k=k, u_ref=u_ref)
-        in_specs.append(pl.BlockSpec((TILE_BLOCKS, S, c_seg),
-                                     lambda i: (i, 0, 0)))
-        args.append(_pad_blocks(u3, nb, gb))
+        args.append(_to_lanes(u, BLOCK, gb))
     words, scales = pl.pallas_call(
-        kern,
+        partial(_encode_kernel, k=k, stochastic=stochastic,
+                interpret=interpret),
         out_shape=(
-            jax.ShapeDtypeStruct((gb, S, w_seg), jnp.uint32),
-            jax.ShapeDtypeStruct((gb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((wpb, gb), jnp.uint32),
+            jax.ShapeDtypeStruct((1, gb), jnp.float32),
         ),
         grid=(grid,),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((TILE_BLOCKS, S, w_seg), lambda i: (i, 0, 0)),
-            pl.BlockSpec((TILE_BLOCKS, 1), lambda i: (i, 0)),
-        ),
+        in_specs=[_tiles(BLOCK, tb)] * len(args),
+        out_specs=(_tiles(wpb, tb), _tiles(1, tb)),
+        scratch_shapes=[pltpu.VMEM((BLOCK, tb), jnp.uint32)],
         interpret=interpret,
     )(*args)
-    return words[:nb].reshape(-1), scales[:nb, 0]
+    return _from_lanes(words, nb), scales[0, :nb]
 
 
 def quant_pack(flat: jax.Array, k: int, *, rng: jax.Array | None = None,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """flat (N,) float -> (words (⌈N/256⌉·8k,) uint32, scales (⌈N/256⌉,)).
 
     Bit-identical to ``codec.quantize_blocks`` + ``codec.pack_bits``."""
@@ -203,48 +298,38 @@ def quant_pack(flat: jax.Array, k: int, *, rng: jax.Array | None = None,
     pad = nb * BLOCK - n
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    S, c_seg, _ = block_layout(k)
-    x3 = flat.reshape(nb, S, c_seg)
-    u3 = None
     if rng is not None:
         # identical draw to the oracle: uniform(rng, (nb, BLOCK))
-        u3 = jax.random.uniform(rng, (nb, BLOCK)).reshape(nb, S, c_seg)
+        u = jax.random.uniform(rng, (nb, BLOCK)).reshape(-1)
     else:
-        u3 = jnp.zeros((0, S, c_seg), jnp.float32)   # unused placeholder
-    return _quant_pack_call(x3, u3, k, rng is not None, interpret)
+        u = jnp.zeros((0,), jnp.float32)         # unused placeholder
+    return _quant_pack_call(flat, u, k, rng is not None, interpret)
 
 
 @partial(jax.jit, static_argnames=("k", "interpret"))
-def _unpack_dequant_call(w3, scales2, k: int, interpret: bool):
-    nb = w3.shape[0]
-    grid = pl.cdiv(nb, TILE_BLOCKS)
-    gb = grid * TILE_BLOCKS
-    S, c_seg, w_seg = block_layout(k)
-    w3 = _pad_blocks(w3, nb, gb)
-    scales2 = _pad_blocks(scales2, nb, gb)
-    x3 = pl.pallas_call(
+def _unpack_dequant_call(words, scales, k: int, interpret: bool):
+    nb = scales.shape[0]
+    tb, grid = _grid(nb)
+    gb = grid * tb
+    wpb = words_per_block(k)
+    sc = jnp.pad(scales, (0, gb - nb)).reshape(1, gb)
+    x = pl.pallas_call(
         partial(_decode_kernel, k=k),
-        out_shape=jax.ShapeDtypeStruct((gb, S, c_seg), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((BLOCK, gb), jnp.float32),
         grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((TILE_BLOCKS, S, w_seg), lambda i: (i, 0, 0)),
-            pl.BlockSpec((TILE_BLOCKS, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((TILE_BLOCKS, S, c_seg), lambda i: (i, 0, 0)),
+        in_specs=[_tiles(wpb, tb), _tiles(1, tb)],
+        out_specs=_tiles(BLOCK, tb),
         interpret=interpret,
-    )(w3, scales2)
-    return x3[:nb].reshape(-1)
+    )(_to_lanes(words, wpb, gb), sc)
+    return _from_lanes(x, nb)
 
 
 def unpack_dequant(words: jax.Array, scales: jax.Array, k: int, n: int, *,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """Inverse of quant_pack -> (n,) fp32.  Matches
     ``codec.unpack_bits`` + ``codec.dequantize_blocks``."""
     assert k in SUPPORTED_K, f"fused path needs 1 <= k <= 16, got {k}"
     nb = scales.shape[0]
-    S, c_seg, w_seg = block_layout(k)
     assert words.shape[0] == nb * words_per_block(k), \
         (words.shape, nb, words_per_block(k))
-    flat = _unpack_dequant_call(words.reshape(nb, S, w_seg),
-                                scales.reshape(nb, 1), k, interpret)
-    return flat[:n]
+    return _unpack_dequant_call(words, scales, k, interpret)[:n]

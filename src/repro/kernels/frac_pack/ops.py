@@ -22,7 +22,9 @@ through this module, so backend selection lives in exactly one place:
                  fast fallback wherever Mosaic isn't available.
   mode=None      auto: "pallas" on TPU, else "jnp" — for EVERY width
                  1..16; fractional widths (32 % k != 0) use the same
-                 kernels via the cross-word-carry segment layout.
+                 kernels via the cross-word-carry segment layout.  A
+                 kernel that fails to compile raises; nothing falls
+                 back to jnp behind the caller.
 
 All modes produce bit-identical blobs ({"words", "scales", "meta"},
 same schema as ``codec.frac_encode_tensor``), with the pure-jnp codec
@@ -48,9 +50,7 @@ VALID_MODES = ("pallas", "pallas_interpret", "jnp")
 def default_mode(kbits: int) -> str:
     """Auto backend selection.  ``REPRO_FRAC_MODE`` (pallas | jnp |
     pallas_interpret) overrides for all consumers — none of them expose
-    the mode parameter, so this is the operational escape hatch.  A
-    'pallas' choice is still subject to the per-k kernel probe in
-    ``_resolve_mode``."""
+    the mode parameter, so this is the operational escape hatch."""
     import os
 
     forced = os.environ.get("REPRO_FRAC_MODE")
@@ -71,62 +71,22 @@ def default_mode(kbits: int) -> str:
     return "jnp"
 
 
-_pallas_ok_cache: dict[int, bool] = {}
-
-
-def _pallas_ok(k: int) -> bool:
-    """Validate the compiled kernel once per bit-width with a tiny
-    concrete probe.  The probe compiles eagerly, so a Mosaic lowering
-    failure is caught HERE — a try/except around the real call could
-    not see it when the caller is itself inside an outer jax.jit (the
-    frac8 optimizer path), where tracing succeeds and the compile error
-    only surfaces at the outer compile.  Real calls then run unguarded,
-    so genuine input errors surface instead of being mislabeled as
-    kernel failures.  The verdict is per-k (Mosaic lowering depends on
-    the lane width 32/k): a failure for one width never disables a
-    width whose probe passed."""
-    if k not in _pallas_ok_cache:
-        try:
-            probe = jnp.zeros((codec.BLOCK,), jnp.float32)
-            w, s = frac_quant_pack.quant_pack(probe, k, interpret=False)
-            frac_quant_pack.unpack_dequant(w, s, k, codec.BLOCK,
-                                           interpret=False)
-            jax.block_until_ready(w)
-            _pallas_ok_cache[k] = True
-        except Exception as e:
-            import warnings
-
-            warnings.warn(
-                f"frac_quant_pack Pallas kernel probe failed for k={k} "
-                f"({type(e).__name__}: {e}); using the fused jnp path "
-                f"for k={k} this process. Set REPRO_FRAC_MODE=jnp to "
-                "silence.", RuntimeWarning)
-            _pallas_ok_cache[k] = False
-    return _pallas_ok_cache[k]
-
-
 def _resolve_mode(kbits: int, mode: str | None) -> str:
     """Shared encode/decode mode resolution.  An explicitly passed
-    pallas mode fails loudly — on a non-word-aligned k or a failing
-    kernel probe — never silently switching backend; only the auto /
-    env-var 'pallas' preference falls back to jnp on probe failure."""
-    explicit = mode is not None
-    if explicit and mode not in VALID_MODES:
+    pallas mode on a width the kernels do not cover raises; nothing
+    ever switches backend behind the caller — a kernel that fails to
+    compile raises where it is called."""
+    if mode is None:
+        return default_mode(kbits)
+    if mode not in VALID_MODES:
         raise ValueError(
             f"mode={mode!r}: expected one of " + " | ".join(VALID_MODES))
-    if explicit and mode.startswith("pallas") \
+    if mode.startswith("pallas") \
             and kbits not in frac_quant_pack.SUPPORTED_K:
         raise ValueError(
             f"mode={mode!r} requires 1 <= k <= 16 "
             f"(fused kernels cover every such width, fractional "
             f"included), got k={kbits}")
-    mode = mode or default_mode(kbits)
-    if mode == "pallas" and not _pallas_ok(kbits):
-        if explicit:
-            raise RuntimeError(
-                f"mode='pallas' requested but the compiled kernel probe "
-                f"failed for k={kbits} (see RuntimeWarning)")
-        return "jnp"
     return mode
 
 

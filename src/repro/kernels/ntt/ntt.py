@@ -12,11 +12,15 @@ Design (Amoeba MPE adaptation, DESIGN.md §2):
   - bit-reversal is done by the ops.py wrapper (a gather is cheap there
     and lane-hostile in-kernel).
 
-TPU layout note: stages with h < 128 are sublane-local after the
-reshape; on real hardware the first log2(128) stages would instead be
-fused into a radix-128 DFT matmul on the MXU — exactly the paper's
-MPE/SHIFT→MVM recoding — which the interpret-mode kernel documents but
-does not need.
+TPU layout: the transform axis stays whole on the 128-wide lane axis.
+Stage ``h`` pairs element ``i`` with ``i ^ h``; rather than splitting
+the lanes into ``(n/2h, 2, h)`` (which Mosaic cannot relayout for
+``h < 128``), every element reads its partner through two lane rolls
+(``x[i+h]``, ``x[i-h]``), picks its role from ``i & h``, and multiplies
+by a per-stage, per-lane twiddle row ``tw[h + i % h]`` (ops.py builds
+the ``(log2 N, N)`` table).  Both halves of a butterfly compute the
+same product, so the stage costs two multiplies per pair instead of
+one, all lane-dense.  Compiled kernels need ``N % 128 == 0``.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 R_BITS = 16
 R = 1 << R_BITS
@@ -61,33 +66,32 @@ def _submod(a, b, q):
 def ntt_kernel(x_ref, tw_ref, o_ref, *, n: int, q: int, q_prime: int,
                n_inv_mont: int):
     """x_ref: (bm, N) int32 bit-reversed standard-domain residues.
-    tw_ref: (N,) int32 Montgomery-form stage twiddles (ref.py layout).
+    tw_ref: (log2 N, N) int32 Montgomery-form per-lane stage twiddles.
     n_inv_mont: N^-1·R mod q for the inverse transform, or 0 (forward).
     """
     x = x_ref[...]
-    tw = tw_ref[...]
-    bm = x.shape[0]
-    h = 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    h, stage = 1, 0
     while h < n:
-        xr = x.reshape(bm, n // (2 * h), 2, h)
-        a = xr[:, :, 0, :]
-        b = xr[:, :, 1, :]
-        t = _mulredc(b, tw[h: 2 * h][None, None, :], q, q_prime)
-        lo = _addmod(a, t, q)
-        hi = _submod(a, t, q)
-        x = jnp.concatenate([lo[:, :, None, :], hi[:, :, None, :]],
-                            axis=2).reshape(bm, n)
-        h *= 2
+        lo_side = (lane & h) == 0
+        a = jnp.where(lo_side, x, pltpu.roll(x, h, 1))         # x[i - h]
+        b = jnp.where(lo_side, pltpu.roll(x, n - h, 1), x)     # x[i + h]
+        t = _mulredc(b, tw_ref[stage:stage + 1, :], q, q_prime)
+        x = jnp.where(lo_side, _addmod(a, t, q), _submod(a, t, q))
+        h, stage = 2 * h, stage + 1
     if n_inv_mont:
         x = _mulredc(x, jnp.int32(n_inv_mont), q, q_prime)
     o_ref[...] = x
 
 
-def ntt_pallas(x_bitrev: jax.Array, tw_mont: jax.Array, *, q: int,
+def ntt_pallas(x_bitrev: jax.Array, tw_lanes: jax.Array, *, q: int,
                inverse: bool, block_batch: int = 8,
-               interpret: bool = True) -> jax.Array:
-    """x_bitrev: (B, N) int32.  Returns the transform, natural order."""
+               interpret: bool = False) -> jax.Array:
+    """x_bitrev: (B, N) int32; tw_lanes: (log2 N, N) int32.  Returns
+    the transform, natural order."""
     B, n = x_bitrev.shape
+    if not interpret and n % 128:
+        raise ValueError(f"compiled NTT needs N % 128 == 0, got N={n}")
     q_prime, r_mod_q, _ = montgomery_constants(q)
     n_inv_mont = (pow(n, q - 2, q) * R) % q if inverse else 0
     bm = min(block_batch, B)
@@ -100,8 +104,8 @@ def ntt_pallas(x_bitrev: jax.Array, tw_mont: jax.Array, *, q: int,
         grid=(B // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec(tw_lanes.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         interpret=interpret,
-    )(x_bitrev, tw_mont)
+    )(x_bitrev, tw_lanes)

@@ -5,21 +5,20 @@
 ``paged_attention`` here; backend selection lives in exactly one place:
 
   mode="pallas"  compiled Pallas page-walk kernel (paged_decode.py) —
-                 per-lane trip count, TPU only, guarded by an eager
-                 probe exactly like frac_pack's.
+                 per-lane trip count, pages DMA'd from HBM, TPU only.
   mode="pallas_interpret"
                  same kernel through the Pallas interpreter (tests /
                  CPU debugging; slow but bit-comparable to "jnp").
   mode="jnp"     vectorized page walk: a ``fori_loop`` over page
                  columns bounded by ``max(pos) // ps + 1`` across the
                  bucket (a traced bound — XLA lowers it to a while
-                 loop), one page column per step, identical per-page
-                 online-softmax math to the kernel.  The transient per
-                 step is ``(B, ps, K, hd)`` keys/values plus a
-                 ``(B, K, G, ps)`` score tile — never the
-                 ``(B, max_pages * ps, K, hd)`` gather.  The fast
-                 fallback wherever Mosaic isn't available.
-  mode=None      auto: "pallas" on TPU (probe permitting), else "jnp".
+                 loop), one page-column chunk per step, the kernel's
+                 own ``fold_chunk`` vmapped over lanes.  The transient
+                 per step is ``(B, chunk*ps, K, hd)`` keys/values plus a
+                 ``(B, K, G, chunk*ps)`` score tile — never the
+                 ``(B, max_pages * ps, K, hd)`` gather.  The CPU path.
+  mode=None      auto: "pallas" on TPU, else "jnp".  A kernel that
+                 fails to compile raises; nothing falls back.
 
 ``REPRO_PAGED_ATTN_MODE`` overrides the auto choice for all consumers —
 the serve engine doesn't expose the mode parameter, so this is the
@@ -66,96 +65,52 @@ def default_mode() -> str:
     return "jnp"
 
 
-_pallas_ok_cache: dict[str, bool] = {}
-
-
-def _pallas_ok() -> bool:
-    """Validate the compiled kernel once per process with a tiny
-    concrete probe — eager, so a Mosaic lowering failure surfaces here
-    rather than inside the serve loop's outer jit (same rationale as
-    frac_pack.ops._pallas_ok)."""
-    if "ok" not in _pallas_ok_cache:
-        try:
-            q = jnp.zeros((2, 4, 8), jnp.float32)
-            pool = jnp.zeros((4, 2, 2, 8), jnp.float32)
-            pt = jnp.array([[1, 2], [3, -1]], jnp.int32)
-            pos = jnp.array([3, 1], jnp.int32)
-            out = paged_decode.paged_attention(q, pool, pool, pt, pos,
-                                               interpret=False)
-            jax.block_until_ready(out)
-            _pallas_ok_cache["ok"] = True
-        except Exception as e:
-            import warnings
-
-            warnings.warn(
-                f"paged_attn Pallas kernel probe failed "
-                f"({type(e).__name__}: {e}); using the jnp page walk "
-                f"this process. Set {ENV_VAR}=jnp to silence.",
-                RuntimeWarning)
-            _pallas_ok_cache["ok"] = False
-    return _pallas_ok_cache["ok"]
-
-
 def _resolve_mode(mode: str | None) -> str:
-    """Explicit "pallas" fails loudly on a failing probe; only the
-    auto / env-var preference falls back to jnp."""
-    explicit = mode is not None
-    if explicit and mode not in VALID_MODES:
+    """An explicit mode wins; otherwise the env var, then the platform.
+    Nothing falls back: a kernel that fails to compile raises."""
+    if mode is None:
+        return default_mode()
+    if mode not in VALID_MODES:
         raise ValueError(
             f"mode={mode!r}: expected one of " + " | ".join(VALID_MODES))
-    if not explicit:
-        mode = default_mode()
-    if mode == "pallas" and not _pallas_ok():
-        if explicit:
-            raise RuntimeError(
-                "mode='pallas' requested but the kernel probe failed "
-                "on this backend; use 'pallas_interpret' or 'jnp'")
-        mode = "jnp"
     return mode
 
 
 def _paged_attention_jnp(q, pk, pv, page_table, pos, chunk):
-    """Vectorized page walk — per-chunk math mirrors the kernel.
-    ``page_table`` width is a multiple of ``chunk`` (padded by the
-    dispatcher)."""
+    """Vectorized page walk: the kernel's ``fold_chunk`` vmapped over
+    lanes, one page-table chunk per step.  ``page_table`` width is a
+    multiple of ``chunk`` (padded by the dispatcher)."""
     B, H, hd = q.shape
     ps, K = pk.shape[1], pk.shape[2]
     G = H // K
+    T = chunk * ps
     max_pages = page_table.shape[1]
-    qg = (q * (hd ** -0.5)).reshape(B, K, G, hd)
+    qg = paged_decode.scaled_query(q, K)
     pos = pos.astype(jnp.int32)
     n_pages = jnp.minimum(jnp.max(pos) // ps + 1, max_pages)
     n_chunks = (n_pages + chunk - 1) // chunk
-    slot = jnp.arange(chunk * ps)                # slot offset in chunk
+    slot = jnp.arange(T)                         # slot offset in chunk
+    fold = jax.vmap(paged_decode.fold_chunk)
 
     def body(t, carry):
-        m, l, acc = carry
         first = t * chunk
         entries = jax.lax.dynamic_slice_in_dim(
             page_table, first, chunk, axis=1)           # (B, chunk)
         pids = jnp.maximum(entries, 0)
-        k = pk[pids].reshape(B, chunk * ps, K, hd)
-        v = pv[pids].reshape(B, chunk * ps, K, hd)
+        k = pk[pids].reshape(B, T, K * hd)
+        v = pv[pids].reshape(B, T, K * hd)
         valid = ((first * ps + slot)[None, :] <= pos[:, None]) \
-            & (entries[:, slot // ps] > 0)              # (B, chunk*ps)
-        s = jnp.einsum("bkgh,bskh->bkgs", qg, k,
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-        v = jnp.where(valid[:, :, None, None], v, jnp.zeros((), v.dtype))
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        r = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l = l * r + p.sum(axis=-1)
-        acc = acc * r[..., None] + jnp.einsum(
-            "bkgs,bskh->bkgh", p, v.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+            & (entries[:, slot // ps] > 0)              # (B, T)
+        return tuple(
+            fold(qg[:, h], k[:, :, h * hd:(h + 1) * hd],
+                 v[:, :, h * hd:(h + 1) * hd], valid[:, None, :],
+                 valid[:, :, None], *carry[h])
+            for h in range(K))
 
-    m0 = jnp.full((B, K, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, K, G), jnp.float32)
-    a0 = jnp.zeros((B, K, G, hd), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1.0)[..., None]
+    carry = jax.lax.fori_loop(0, n_chunks, body,
+                              paged_decode.init_carry(K, G, hd, (B,)))
+    out = jnp.stack([acc / jnp.maximum(l, 1.0) for _, l, acc in carry],
+                    axis=1)                             # (B, K, G, hd)
     return out.reshape(B, H, hd).astype(q.dtype)
 
 

@@ -1,14 +1,20 @@
 """Pallas paged-attention decode kernel: attend through the page table.
 
-One grid step per lane.  The kernel reads the lane's row of the page
-table and walks ONLY its ``ceil((pos+1)/page_size)`` allocated pages,
-folding each page's keys/values into a running flash-attention
-accumulator ``(m, l, acc)`` — the gathered contiguous
-``(B, max_pages * page_size, K, hd)`` cache that ``common.gather_pages``
-materializes never exists.  A short lane in a bucket whose anchor
-request pinned a wide page table does attention work proportional to
-its OWN length, not the bucket max: the transient per step is one
-``(page_size, K, hd)`` page plus the ``(K, G, page_size)`` score tile.
+One grid step per lane.  The lane's page-table row and decode position
+arrive as scalar prefetch (SMEM); the K/V pools stay in HBM
+(``pl.ANY``).  The kernel walks ONLY the lane's
+``ceil((pos+1)/page_size)`` pages, ``chunk`` pages per step: each step
+DMAs the next chunk's pages into the other half of a double-buffered
+VMEM scratch while it folds the current chunk into a running
+flash-attention accumulator ``(m, l, acc)`` per KV head.  The gathered
+contiguous ``(B, max_pages * page_size, K, hd)`` cache that
+``common.gather_pages`` materializes never exists, and VMEM holds two
+chunks of pages whatever the pool size.
+
+Layout: a pool leaf ``(P, ps, K, hd)`` is viewed as ``(P, ps, K*hd)``
+(a free reshape), so one page is one contiguous DMA and KV head ``h``
+is the lane-aligned column slice ``[h*hd, (h+1)*hd)`` of the chunk
+buffer.  The query arrives pre-scaled as ``(B, K, G, hd)``.
 
 Index math (mirrors serve/paging.py's layout):
 
@@ -19,34 +25,30 @@ Index math (mirrors serve/paging.py's layout):
 
 Page-table entries are ``-1`` when unallocated and ``0`` is the
 reserved trash page (serve/paging.py ``TRASH_PAGE``); both are invalid
-for reads, so validity is ``entry > 0``.  Invalid slots get a
-``NEG_INF`` score (softmax weight 0) AND their value rows are zeroed
-with ``jnp.where`` before the weighted sum — a NaN/inf-poisoned trash
-page must not leak through ``0 * NaN`` (locked by the poisoned-pool
-test in tests/test_serve_paged.py).
+for reads, so validity is ``entry > 0`` (invalid entries DMA the trash
+page, whose contents never matter).  Invalid slots get a ``NEG_INF``
+score (softmax weight 0) AND their value rows are zeroed with
+``jnp.where`` before the weighted sum — a NaN/inf-poisoned trash page
+must not leak through ``0 * NaN`` (locked by the poisoned-pool test in
+tests/test_serve_paged.py).
 
-Online-softmax update per page (all fp32):
+Online-softmax update per chunk and KV head (all fp32, ``fold_chunk``):
 
   m' = max(m, max_s)          r = exp(m - m')
   p  = exp(s - m')            l' = l * r + sum(p)
   acc' = acc * r + p @ v      out = acc / l      (l >= 1 for live lanes)
 
-A fully-masked lane (dead: every entry <= 0) keeps ``l == 0``; the
+A fully-masked lane (dead: every entry <= 0) keeps ``acc == 0``; the
 epilogue divides by ``max(l, 1)`` so its output is exact zeros —
 garbage-but-finite, same contract as the gather oracle, and the serve
 loop discards dead lanes' tokens anyway.
 
-The per-lane math is kept term-for-term identical to the ``jnp`` walk
-in ops.py (same einsums, same fp32 promotion points), so interpret-mode
-runs are bit-comparable against it; the gather + ``common.attention``
-oracle differs in reduction ORDER (full-row softmax, probs cast to the
-value dtype before the weighted sum), so kernel-vs-oracle equality is
-asserted at allclose / greedy-token level, not float-bit level.
-
-Like the other kernels in this package family the pool is handed to the
-kernel whole (one BlockSpec covering the full array); at real TPU pool
-sizes this would want ANY-memory residency + per-page DMA, which is why
-the compiled path stays behind ops.py's eager probe.
+``fold_chunk`` is shared with the ``jnp`` walk in ops.py (which vmaps
+it over lanes), so interpret-mode runs are bit-comparable against it;
+the gather + ``common.attention`` oracle differs in reduction ORDER
+(full-row softmax, probs cast to the value dtype before the weighted
+sum), so kernel-vs-oracle equality is asserted within a tolerance,
+not at float-bit level.
 """
 from __future__ import annotations
 
@@ -55,60 +57,105 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # finite, matches common.NEG_INF: masked != NaN
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _paged_attn_kernel(q_ref, pt_ref, pos_ref, pk_ref, pv_ref, o_ref, *,
-                       page_size: int, chunk: int):
-    H, hd = q_ref.shape[1], q_ref.shape[2]
-    K = pk_ref.shape[2]
-    G = H // K
+def scaled_query(q: jax.Array, num_kv_heads: int) -> jax.Array:
+    """(B, H, hd) -> (B, K, G, hd) scaled in the input dtype, exactly
+    like the oracle's ``q.reshape(...) * hd**-0.5`` (common.attention)."""
+    B, H, hd = q.shape
+    return (q * (hd ** -0.5)).reshape(B, num_kv_heads, H // num_kv_heads, hd)
+
+
+def fold_chunk(q, k, v, valid_row, valid_col, m, l, acc):
+    """Fold one chunk of one KV head into the accumulator.
+
+    q (G, hd); k, v (T, hd); valid_row (1, T) / valid_col (T, 1) bool;
+    m, l (G, 1) fp32; acc (G, hd) fp32."""
+    # fp32 operands get the exact multi-pass MXU product; Mosaic takes
+    # no precision for bf16 operands (their product is exact anyway)
+    s = jnp.einsum("gh,th->gt", q, k,
+                   precision=_HI if q.dtype == jnp.float32 else None,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(valid_row, s, NEG_INF)
+    v = jnp.where(valid_col, v, jnp.zeros((), v.dtype))
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    r = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = l * r + p.sum(axis=-1, keepdims=True)
+    acc = acc * r + jnp.einsum("gt,th->gh", p, v.astype(jnp.float32),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def init_carry(K: int, G: int, hd: int, lead: tuple = ()):
+    return tuple(
+        (jnp.full((*lead, G, 1), NEG_INF, jnp.float32),
+         jnp.zeros((*lead, G, 1), jnp.float32),
+         jnp.zeros((*lead, G, hd), jnp.float32))
+        for _ in range(K))
+
+
+def _paged_attn_kernel(pt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
+                       kbuf, vbuf, sem, *, page_size: int, chunk: int):
+    b = pl.program_id(0)
+    _, K, G, hd = q_ref.shape
+    ps = page_size
+    T = chunk * ps
     max_pages = pt_ref.shape[1]          # padded to a multiple of chunk
-
-    # scale in the input dtype, exactly like the oracle's
-    # q.reshape(...) * hd**-0.5 (common.attention)
-    qg = (q_ref[0] * (hd ** -0.5)).reshape(K, G, hd)
-    pos = pos_ref[0, 0]
-    n_pages = jnp.minimum(pos // page_size + 1, max_pages)
+    pos = pos_ref[b]
+    n_pages = jnp.minimum(pos // ps + 1, max_pages)
     n_chunks = (n_pages + chunk - 1) // chunk
-    slot = jnp.arange(chunk * page_size)         # slot offset in chunk
+
+    def copies(t, slot):
+        out = []
+        for j in range(chunk):
+            pid = jnp.maximum(pt_ref[b, t * chunk + j], 0)
+            dst = pl.ds(j * ps, ps)
+            out.append(pltpu.make_async_copy(
+                pk_hbm.at[pid], kbuf.at[slot, dst], sem.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                pv_hbm.at[pid], vbuf.at[slot, dst], sem.at[1, slot]))
+        return out
+
+    for cp in copies(0, 0):
+        cp.start()
 
     def body(t, carry):
-        m, l, acc = carry
-        first = t * chunk
-        entries = pl.load(pt_ref, (pl.ds(0, 1), pl.ds(first, chunk)))[0]
-        pids = jnp.maximum(entries, 0)
-        # scattered page ids: one static slice per chunk member
-        ks, vs = [], []
-        for j in range(chunk):
-            page = (pl.ds(pids[j], 1), slice(None), slice(None),
-                    slice(None))
-            ks.append(pl.load(pk_ref, page)[0])
-            vs.append(pl.load(pv_ref, page)[0])
-        k = jnp.concatenate(ks, axis=0)          # (chunk*ps, K, hd)
-        v = jnp.concatenate(vs, axis=0)
-        valid = (first * page_size + slot <= pos) \
-            & (entries[slot // page_size] > 0)
-        s = jnp.einsum("kgh,skh->kgs", qg, k,
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(valid[None, None, :], s, NEG_INF)
-        v = jnp.where(valid[:, None, None], v, jnp.zeros((), v.dtype))
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        r = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l = l * r + p.sum(axis=-1)
-        acc = acc * r[..., None] + jnp.einsum(
-            "kgs,skh->kgh", p, v.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        slot = t % 2
 
-    m0 = jnp.full((K, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((K, G), jnp.float32)
-    a0 = jnp.zeros((K, G, hd), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1.0)[..., None]
-    o_ref[0] = out.reshape(H, hd).astype(o_ref.dtype)
+        @pl.when(t + 1 < n_chunks)
+        def _():
+            for cp in copies(t + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(t, slot):
+            cp.wait()
+        first = t * chunk
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+
+        def valid(s_ids):
+            ok = s_ids < 0                       # all False
+            for j in range(chunk):
+                live = pt_ref[b, first + j] > 0
+                ok = ok | ((s_ids >= j * ps) & (s_ids < (j + 1) * ps) & live)
+            return ok & (first * ps + s_ids <= pos)
+
+        vrow, vcol = valid(row), valid(col)
+        kc, vc = kbuf[slot], vbuf[slot]          # (T, K*hd)
+        return tuple(
+            fold_chunk(q_ref[0, h], kc[:, h * hd:(h + 1) * hd],
+                       vc[:, h * hd:(h + 1) * hd], vrow, vcol, *carry[h])
+            for h in range(K))
+
+    carry = jax.lax.fori_loop(0, n_chunks, body, init_carry(K, G, hd))
+    for h, (_, l, acc) in enumerate(carry):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array,          # (B, H, hd) decode query
@@ -117,7 +164,7 @@ def paged_attention(q: jax.Array,          # (B, H, hd) decode query
                     page_table: jax.Array,  # (B, max_pages) int32,
                                             # max_pages % chunk == 0
                     pos: jax.Array,         # (B,) int32 decode positions
-                    *, chunk: int = 1, interpret: bool = True) -> jax.Array:
+                    *, chunk: int = 1, interpret: bool = False) -> jax.Array:
     """Fused paged GQA decode attention.  Returns (B, H, hd) in q.dtype.
 
     ``chunk`` pages fold into the accumulator per loop step (ops.py
@@ -128,21 +175,27 @@ def paged_attention(q: jax.Array,          # (B, H, hd) decode query
     B, H, hd = q.shape
     P, ps, K, _ = pk.shape
     max_pages = page_table.shape[1]
-    assert H % K == 0, (H, K)
-    assert max_pages % chunk == 0, (max_pages, chunk)
-    pos2d = pos.astype(jnp.int32).reshape(B, 1)
-    pool_spec = pl.BlockSpec((P, ps, K, hd), lambda b: (0, 0, 0, 0))
-    return pl.pallas_call(
+    if H % K or max_pages % chunk:
+        raise ValueError(f"heads {H} % kv_heads {K}, table width "
+                         f"{max_pages} % chunk {chunk} must both be 0")
+    G = H // K
+    qg = scaled_query(q, K)
+    blk = pl.BlockSpec((1, K, G, hd), lambda b, pt, pos: (b, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
         partial(_paged_attn_kernel, page_size=ps, chunk=chunk),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, max_pages), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pool_spec,
-            pool_spec,
-        ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[blk, pool, pool],
+            out_specs=blk,
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk * ps, K * hd), pk.dtype),
+                pltpu.VMEM((2, chunk * ps, K * hd), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
-    )(q, page_table.astype(jnp.int32), pos2d, pk, pv)
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
+      pk.reshape(P, ps, K * hd), pv.reshape(P, ps, K * hd))
+    return out.reshape(B, H, hd)

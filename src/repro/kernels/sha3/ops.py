@@ -1,9 +1,11 @@
 """jit'd SHA3-256 over the Pallas Keccak kernel + checkpoint hashing.
 
 ``sha3_256`` is the TPU-path batch hasher (rate 1088 / state 1600 per
-the paper's benchmark).  The checkpoint manager hashes shards with this
-code path's semantics; on CPU hosts it may use hashlib (identical
-digests — property-tested) for speed.
+the paper's benchmark).  It compiles the kernel unless the caller
+passes ``interpret=True`` (the Pallas interpreter: CPU tests/hosts).
+The checkpoint manager hashes shards with this code path's semantics;
+on CPU hosts it may use hashlib (identical digests — property-tested)
+for speed.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ def _to_u64(pairs: np.ndarray) -> np.ndarray:
         | pairs[..., 0].astype(np.uint64)
 
 
-def sha3_256(msgs: list[bytes], interpret: bool = True) -> list[bytes]:
+def sha3_256(msgs: list[bytes], interpret: bool = False) -> list[bytes]:
     """Batched SHA3-256 via the Pallas Keccak-f kernel."""
     blocks, nb = ref.pad_messages(msgs)          # (B, max_blocks, 17) u64
     B, max_blocks, _ = blocks.shape
@@ -42,11 +44,11 @@ def sha3_256(msgs: list[bytes], interpret: bool = True) -> list[bytes]:
     return [bytes(dig[i]) for i in range(B)]
 
 
-def hash_bytes(data: bytes, interpret: bool = True) -> bytes:
+def hash_bytes(data: bytes, interpret: bool = False) -> bytes:
     return sha3_256([data], interpret=interpret)[0]
 
 
-def hash_array(x, interpret: bool = True) -> bytes:
+def hash_array(x, interpret: bool = False) -> bytes:
     """Digest of a tensor's raw bytes (checkpoint shard integrity)."""
     return hash_bytes(np.ascontiguousarray(np.asarray(x)).tobytes(),
                       interpret=interpret)

@@ -9,6 +9,7 @@ import traceback
 
 import jax
 
+from repro import hw
 from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
 from repro.core.ese.records import RooflineRecord
 from repro.launch.mesh import make_production_mesh
@@ -77,6 +78,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, tag: str = "baseline",
         collective_bytes=float(sum(coll_by_kind.values())),
         model_flops=model_flops_for(cfg, shape, _n_active_matmul(cfg)),
         chips=chips,
+        device_kind=hw.V5E.kind,        # the production mesh is a v5e pod
     )
     # typed round-trip: the ESE record validates the cell at write time,
     # so dryrun.json always matches what RooflineRecord.from_cell expects

@@ -16,9 +16,9 @@ optimized HLO text itself with a computation-graph walk:
                 operand) bytes as the per-device wire-bytes proxy,
                 trip-count aware
 
-Terms (TPU v5e): t_comp = flops/197e12, t_mem = bytes/819e9,
-t_coll = coll_bytes/50e9.  ``cost_analysis()`` raw numbers are recorded
-alongside for reference.
+Terms (peaks from ``repro.hw`` for the record's device kind; TPU v5e:
+t_comp = flops/197e12, t_mem = bytes/819e9, t_coll = coll_bytes/50e9).
+``cost_analysis()`` raw numbers are recorded alongside for reference.
 """
 from __future__ import annotations
 
@@ -365,18 +365,23 @@ class Roofline:
     collective_bytes: float       # per-device wire bytes
     model_flops: float            # 6·N_active·D (whole step, all chips)
     chips: int
+    device_kind: str              # repro.hw table key of the target chip
+
+    @property
+    def peaks(self) -> hw.Chip:
+        return hw.chip(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / hw.PEAK_FLOPS_BF16
+        return self.flops / self.peaks.peak_flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / hw.HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / hw.ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -403,7 +408,7 @@ class Roofline:
         t = self.step_time
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.chips * hw.PEAK_FLOPS_BF16 * t)
+        return self.model_flops / (self.chips * self.peaks.peak_flops_bf16 * t)
 
     def as_dict(self) -> dict:
         return {

@@ -1,12 +1,19 @@
 """Serving launcher: continuous-batched requests against a checkpoint
-(or random init for shape testing).
+(or seeded random weights for shape testing).
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b \
-        [--ckpt /tmp/run1] --requests 8 --max-new 16 [--mixed-lengths]
+        [--preset tiny|full] [--seed 0] [--ckpt /tmp/run1] \
+        --requests 8 --max-new 16 [--mixed-lengths] \
+        [--paged] [--kv-frac-kbits 8]
 
+``--preset full`` serves the published width and depth (one v5e chip
+holds llama3.2-3b whole); ``tiny`` is the same family cut down for CPU.
 ``--mixed-lengths`` submits a spread of prompt lengths; families that
 support ragged buckets (model.supports_ragged) then serve them through
 one right-padded prefill per bucket instead of one bucket per length.
+``--paged`` reads the KV pool through the page-walk attention
+(kernels/paged_attn: the compiled Pallas kernel on a TPU, the jnp walk
+elsewhere).
 """
 from __future__ import annotations
 
@@ -15,14 +22,46 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import ARCH_IDS, get_tiny
+from repro.configs import ARCH_IDS, get_config, get_tiny
+from repro.launch.cache import use_compile_cache
 from repro.models import model
 from repro.serve.engine import ServeEngine
 
 
-def main() -> None:
+def load_params(mcfg, *, seed: int = 0, ckpt: str | None = None):
+    """Weights from a checkpoint, else seeded random ones drawn on the
+    device in their own dtype (no fp32 copy of the whole tree)."""
+    if ckpt:
+        from repro.train.checkpoint import CheckpointManager
+
+        mgr = CheckpointManager(ckpt)
+        tpl = {"params": model.abstract_params(mcfg)}
+        tree, _ = mgr.restore(tpl)
+        return jax.tree.map(jax.numpy.asarray, tree["params"])
+    return model.init_params_jit(mcfg, jax.random.PRNGKey(seed))
+
+
+def serve_requests(mcfg, params, prompts, max_new, *, engine=None,
+                   max_wall_s: float | None = None, **engine_kw):
+    """Submit ``prompts`` (one max_new each, or one int for all) and
+    serve them to completion.  Pass ``engine`` to reuse a warm one,
+    else ``engine_kw`` build a ``ServeEngine``.  Returns
+    (engine, {rid: tokens} for these requests)."""
+    eng = engine or ServeEngine(mcfg, params, **engine_kw)
+    if isinstance(max_new, int):
+        max_new = [max_new] * len(prompts)
+    rids = [eng.submit(p, max_new_tokens=m, max_wall_s=max_wall_s)
+            for p, m in zip(prompts, max_new)]
+    out = eng.run()
+    return eng, {r: out[r] for r in rids}
+
+
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
+    ap.add_argument("--preset", default="tiny", choices=["tiny", "full"])
+    ap.add_argument("--seed", type=int, default=0,
+                    help="random-weight and prompt seed (no --ckpt)")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -53,18 +92,12 @@ def main() -> None:
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request wall deadline; expired requests "
                          "return whatever they produced")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    mcfg = get_tiny(args.arch)
-    if args.ckpt:
-        from repro.train.checkpoint import CheckpointManager
-
-        mgr = CheckpointManager(args.ckpt)
-        tpl = {"params": model.abstract_params(mcfg)}
-        tree, _ = mgr.restore(tpl)
-        params = jax.tree.map(jax.numpy.asarray, tree["params"])
-    else:
-        params = model.init_params(mcfg, jax.random.PRNGKey(0))
+    use_compile_cache()
+    mcfg = get_tiny(args.arch) if args.preset == "tiny" \
+        else get_config(args.arch)
+    params = load_params(mcfg, seed=args.seed, ckpt=args.ckpt)
 
     flash = None
     if args.flash_oversubscribe:
@@ -76,19 +109,20 @@ def main() -> None:
             RecycledChip(n_blocks=args.flash_blocks, seed=args.flash_seed),
             faults=FaultConfig(seed=args.flash_seed,
                                rber_scale=args.flash_rber_scale))
-    eng = ServeEngine(mcfg, params, max_batch=args.max_batch,
-                      kv_frac_kbits=args.kv_frac_kbits,
-                      paged=args.paged, page_size=args.page_size,
-                      flash=flash)
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
+    prompts = []
     for i in range(args.requests):
         plen = args.prompt_len
         if args.mixed_lengths:
             plen = max(2, args.prompt_len - (i % 4) * 2)
-        eng.submit(rng.integers(1, mcfg.vocab_size, plen).astype(np.int32),
-                   max_new_tokens=args.max_new,
-                   max_wall_s=args.deadline_s)
-    out = eng.run()
+        prompts.append(rng.integers(1, mcfg.vocab_size, plen).astype(np.int32))
+    eng, out = serve_requests(
+        mcfg, params, prompts, args.max_new, max_wall_s=args.deadline_s,
+        max_batch=args.max_batch, kv_frac_kbits=args.kv_frac_kbits,
+        paged=args.paged, page_size=args.page_size,
+        paged_kernel=args.paged, flash=flash)
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}")
     for rid, toks in out.items():
         print(f"req {rid}: {toks}")
     s = eng.stats

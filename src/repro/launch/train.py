@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config, get_tiny
 from repro.core.power import traces
 from repro.core.power.scheduler import CarbonAwareScheduler, SchedulerConfig
+from repro.launch.cache import use_compile_cache
 from repro.train.loop import Trainer, TrainerConfig
 
 
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--grad-compress", type=int, default=16)
     args = ap.parse_args()
 
+    use_compile_cache()
     mcfg = get_tiny(args.arch) if args.preset == "tiny" else get_config(args.arch)
     trace = None
     sch = None

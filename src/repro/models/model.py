@@ -45,6 +45,19 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Pytree:
     return tree_init(param_specs(cfg), rng)
 
 
+def init_params_jit(cfg: ModelConfig, rng: jax.Array,
+                    shardings: Pytree | None = None) -> Pytree:
+    """``init_params`` as one jitted program: each leaf is drawn and
+    cast inside it, so no fp32 copy of a whole leaf is ever resident,
+    and ``shardings`` (a NamedSharding tree, e.g.
+    ``rules.param_shardings``) places every leaf where it is created —
+    never whole on one device first.  Same distribution as
+    ``init_params``, not the same bits."""
+    specs = param_specs(cfg)
+    return jax.jit(lambda key: tree_init(specs, key),
+                   out_shardings=shardings)(rng)
+
+
 def abstract_params(cfg: ModelConfig) -> Pytree:
     return tree_abstract(param_specs(cfg))
 

@@ -90,6 +90,7 @@ import os
 import time
 import warnings
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +126,8 @@ class ServeStats:
     prefills: int = 0
     decode_steps: int = 0           # device loop iterations (from the loop)
     host_syncs: int = 0             # decode-phase host transfers (1/bucket)
+    decode_s: float = 0.0           # wall from first token ready to the
+                                    # bucket's host transfer (decode phase)
     ttft_s: list[float] = field(default_factory=list)
     kv_bytes_full: int = 0          # fp bytes the caches would occupy
     kv_bytes_frac: int = 0          # bytes after the FRAC kbits dial
@@ -151,6 +154,17 @@ class ServeStats:
     reprefills: int = 0             # stage 3: lanes replayed from prompt
     reprefill_tokens: int = 0       # prompt tokens recomputed by stage 3
     flash_bytes_peak: int = 0       # max bytes live on the spill tier
+
+
+@partial(jax.jit, static_argnums=1)
+def _frac_kv_rows(cache, kbits: int):
+    """Slot-granular FRAC fake-quant of a prefill KV cache (one scale
+    per (K, hd) row), as one fused program: eagerly, each step would
+    hold a full fp32 copy of the cache."""
+    from repro.kernels.frac_pack import ops as fops
+
+    return jax.tree.map(
+        lambda leaf: fops.fake_quant_slots(leaf, kbits, row_dims=2), cache)
 
 
 def build_decode_loop(mcfg: ModelConfig, *, eos_id: int | None = None,
@@ -648,6 +662,7 @@ class ServeEngine:
         now = time.time()
         self.stats.decode_steps += int(steps_np)
         self._note_steps(now - t_first, int(steps_np))
+        self.stats.decode_s += now - t_first
         self._finish_bucket(bucket, out_np, n_np, now, now - t_bucket0,
                             lambda i: bucket_kv_frac // B)
 
@@ -700,8 +715,6 @@ class ServeEngine:
         carries its own pages' FRAC bytes, and ``stats.kv_bytes_peak``
         tracks the true high-water mark of concurrently live pages.
         """
-        from repro.kernels.frac_pack import ops as fops
-
         nb = min(self.max_batch, len(self._pending))
         reqs = self._pending[: nb + self.stage_depth]
         staged_n = len(reqs) - nb
@@ -714,10 +727,7 @@ class ServeEngine:
             # same slot-granular fake-quant as the contiguous FRAC tier
             # (one scale per (K, hd) row) — page layout changes where
             # bytes LIVE, never a lane's numerics
-            cache = jax.tree.map(
-                lambda leaf: fops.fake_quant_slots(
-                    leaf, self.kv_frac_kbits, row_dims=2),
-                cache)
+            cache = _frac_kv_rows(cache, self.kv_frac_kbits)
         # pow2=True bounds the compiled loop variants (pool + table
         # shapes round up; spare pages idle on the free stack) — B and
         # Q are already bounded by max_batch / stage_depth, out_cap by
@@ -734,6 +744,7 @@ class ServeEngine:
             lambda spec, leaf: paging.fill_pool(
                 jnp.zeros(spec.shape, leaf.dtype), leaf, pi, oi),
             pool_specs, cache, is_leaf=is_leaf_spec)
+        del cache                   # the pool holds the prompt KV now
         pt = jnp.asarray(plan.page_table)
         spt = jnp.asarray(plan.staged_pt)
         fs = jnp.asarray(plan.free_stack)
@@ -766,6 +777,7 @@ class ServeEngine:
         now = time.time()
         self.stats.decode_steps += int(steps_np)
         self._note_steps(now - t_first, int(steps_np))
+        self.stats.decode_s += now - t_first
         self.stats.admissions += int(adm_np)
         assert int(adm_np) == staged_n, "stage queue not drained in-loop"
         self._note_attn_transient(nb, plan.page_table.shape[1])
@@ -825,11 +837,7 @@ class ServeEngine:
             self.params, {"tokens": jnp.asarray(prompts)}, jnp.asarray(lens))
         self.stats.prefills += 1
         if self.kv_frac_kbits is not None:
-            from repro.kernels.frac_pack import ops as fops
-
-            cache = jax.tree.map(
-                lambda leaf: fops.fake_quant_slots(
-                    leaf, self.kv_frac_kbits, row_dims=2), cache)
+            cache = _frac_kv_rows(cache, self.kv_frac_kbits)
         leaves, treedef = jax.tree.flatten(cache)
         tok0_np = np.asarray(jax.device_get(tok0))
         t_first = time.time()
@@ -974,11 +982,7 @@ class ServeEngine:
             self.params, {"tokens": jnp.asarray(prompts)}, jnp.asarray(lens))
         self.stats.prefills += 1
         if self.kv_frac_kbits is not None:
-            from repro.kernels.frac_pack import ops as fops
-
-            cache = jax.tree.map(
-                lambda leaf: fops.fake_quant_slots(
-                    leaf, self.kv_frac_kbits, row_dims=2), cache)
+            cache = _frac_kv_rows(cache, self.kv_frac_kbits)
         tok0_np, rp = jax.device_get((tok0, jax.tree.leaves(cache)))
         self.stats.host_syncs += 1           # recovery overhead
         for i, j in enumerate(failed):
@@ -1033,6 +1037,7 @@ class ServeEngine:
         now = time.time()
         self.stats.decode_steps += int(steps_np)
         self._note_steps(now - t_wave0, int(steps_np))
+        self.stats.decode_s += now - t_wave0
         assert int(adm_np) == 0
         self.stats.oversub_waves += 1
         self._note_attn_transient(len(wreqs), plan.page_table.shape[1])

@@ -3,21 +3,6 @@ import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.dirname(__file__))
-
-try:                                    # optional dep: fall back to the
-    import hypothesis                   # noqa: F401  deterministic shim
-except ModuleNotFoundError:
-    import types
-
-    import _hypothesis_fallback as _hf
-
-    _mod = types.ModuleType("hypothesis")
-    _mod.given = _hf.given
-    _mod.settings = _hf.settings
-    _mod.strategies = _hf
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _hf
 
 import pytest  # noqa: E402
 

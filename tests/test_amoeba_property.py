@@ -5,8 +5,7 @@ lock the algebraic contracts the reconfiguration runtime leans on:
 ``ape_add`` is 2^32 addition, ``amoeba_mul`` is constant multiplication
 mod 2^32, ``cyclic_permute_mvm`` is exactly ``jnp.roll`` for any shift
 and width, and ``ape_lut`` returns the stored value on a hit and zero
-on a miss.  Runs under real hypothesis or the deterministic fallback
-shim (conftest.py).
+on a miss.  Driven by hypothesis.
 """
 import jax.numpy as jnp
 import numpy as np

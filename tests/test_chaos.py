@@ -10,8 +10,7 @@ Locks the PR's robustness guarantees:
     rungs are exactly ``DEGRADE_LADDER``;
   - ``RetrySchedule`` properties: deterministic per seed, bounded by
     the cap, non-decreasing before jitter, hedges strictly before the
-    deadline (hypothesis, or the deterministic shim in
-    ``tests/_hypothesis_fallback.py``);
+    deadline (hypothesis);
   - the ``detail["robustness"]`` block round-trips through the
     ``ese-fleet-report/v1`` validator and drift is rejected;
   - recovery work lands in each meter's
@@ -186,7 +185,7 @@ def test_degradation_ladder_monotone_and_locked():
 
 
 # ---------------------------------------------------------------------------
-# retry / hedge schedule properties (hypothesis or the fallback shim)
+# retry / hedge schedule properties (hypothesis)
 # ---------------------------------------------------------------------------
 @settings(max_examples=30, deadline=None)
 @given(st.integers(min_value=0, max_value=2 ** 40),

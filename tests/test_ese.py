@@ -55,7 +55,7 @@ def test_operational_energy_model():
     se = energy.operational_step_energy(_roofline())
     from repro import hw
 
-    assert hw.CHIP_IDLE_W < se.chip_w <= hw.CHIP_TDP_W
+    assert hw.V5E.idle_w < se.chip_w <= hw.V5E.tdp_w
     # facility overheads: PUE and delivery loss are applied
     base = (se.chip_w + hw.HOST_OVERHEAD_W) * 256
     assert se.step_j == pytest.approx(base * 1.06 * hw.PUE, rel=1e-6)

@@ -43,7 +43,7 @@ def test_roofline_record_matches_launch_roofline():
     from repro.launch.roofline import Roofline
 
     rl = Roofline(flops=1e12, hbm_bytes=1e10, collective_bytes=1e9,
-                  model_flops=2e14, chips=64)
+                  model_flops=2e14, chips=64, device_kind="TPU v5 lite")
     rec = RooflineRecord.from_dict(rl.as_dict())
     # the typed record reproduces the dry-run on-disk schema exactly
     assert rec.to_dict() == rl.as_dict()

@@ -1,5 +1,5 @@
 """Property suite for the flash wear / graceful-degradation models
-(core/frac/wear.py, core/frac/policy.py) — shim-compatible hypothesis
+(core/frac/wear.py, core/frac/policy.py) — hypothesis
 (integers / sampled_from / binary only).
 
 Locks the model facts the spill tier and the capacity bench lean on:

@@ -28,10 +28,10 @@ def test_pack32_matches_codec(k, n_words):
     n = n_words * (32 // k)
     rng = np.random.default_rng(k * n_words)
     codes = jnp.asarray(rng.integers(0, 1 << k, n), jnp.uint32)
-    got = pack32(codes, k)
+    got = pack32(codes, k, interpret=True)
     want = codec.pack_bits(codes, k)
     assert (np.asarray(got) == np.asarray(want)).all()
-    back = unpack32(got, k, n)
+    back = unpack32(got, k, n, interpret=True)
     assert (np.asarray(back) == np.asarray(codes)).all()
 
 
@@ -250,3 +250,32 @@ def test_compressed_nbytes_single_source_of_truth():
             blob = codec.frac_encode_tensor(x, kbits=k)
             assert fops.compressed_nbytes(n, k) \
                 == fops.compressed_bytes(blob), (k, n)
+
+
+@pytest.mark.parametrize("off", [-2, -1, 0, 1, 2])
+def test_div_rn_recovers_ieee_quotient(off):
+    """The compiled encode kernel repairs the TPU's inexact f32 division
+    with two ``div_rn`` steps; given a quotient up to two ulps off
+    either way (or exact) they return the IEEE quotient the codec
+    divides to.  Run op by op:
+    a fused CPU program could contract its products into FMAs, which
+    the v5e VPU, where the repair runs, does not have."""
+    rng = np.random.default_rng(7)
+    n = 1 << 16
+    x = (rng.standard_normal(n) * 3).astype(np.float32)
+    s = np.maximum(np.abs(rng.standard_normal(n)).astype(np.float32) * 3,
+                   np.abs(x)) + np.float32(1e-12)
+    ties = np.float32([1.0, -3.0, 0.5, 2.5, 0.0])    # x == s, exact halves
+    x = np.concatenate([x, ties])
+    s = np.concatenate([s, np.float32([3.0, 3.0, 1.0, 2.5, 1.0])])
+    want = x / s
+    r = want
+    for _ in range(abs(off)):
+        r = np.nextafter(r, np.float32(off * np.inf))
+    r = np.where(want == 0, want, r)        # subnormal quotients: out of range
+    x, s = jnp.asarray(x), jnp.asarray(s)
+    got = frac_quant_pack.div_rn(x, s, jnp.asarray(r))
+    if abs(off) == 2:
+        assert (np.asarray(got) != want).any()     # one step: one ulp
+    got = np.asarray(frac_quant_pack.div_rn(x, s, got))
+    assert (got == want).all()

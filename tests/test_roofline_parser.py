@@ -125,7 +125,8 @@ print("COLL", sorted(coll))
 
 def test_roofline_record_math():
     rl = Roofline(flops=197e12, hbm_bytes=819e9, collective_bytes=25e9,
-                  model_flops=197e12 * 256, chips=256)
+                  model_flops=197e12 * 256, chips=256,
+                  device_kind="TPU v5 lite")
     assert rl.t_compute == pytest.approx(1.0)
     assert rl.t_memory == pytest.approx(1.0)
     assert rl.t_collective == pytest.approx(0.5)

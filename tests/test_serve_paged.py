@@ -2,7 +2,7 @@
 
 Three layers of lock:
 
-1. **Allocator properties** (hypothesis, shim-compatible): arbitrary
+1. **Allocator properties** (hypothesis): arbitrary
    admit/grow/finish interleavings driven through the *same* jnp
    primitives the jitted decode loop uses (``paging.alloc_pages`` /
    ``free_lane_pages``) preserve free-list conservation, never alias a
