@@ -196,6 +196,7 @@ def paged_attention(q: jax.Array,          # (B, H, hd) decode query
             ]),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
       pk.reshape(P, ps, K * hd), pv.reshape(P, ps, K * hd))
     return out.reshape(B, H, hd)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.models import mamba as mamba_mod
 from repro.models.common import (
@@ -252,39 +253,41 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pc, page_table, pos,
     ppos = pos[:, None]                                    # (B, 1)
     q = apply_rope(q, ppos, cfg.rope_theta)
     k = apply_rope(k, ppos, cfg.rope_theta)
-    if kv_kbits is not None:
-        from repro.kernels.frac_pack import ops as fops
+    with jax.named_scope(spans.KV_WRITE):
+        if kv_kbits is not None:
+            from repro.kernels.frac_pack import ops as fops
 
-        k = fops.fake_quant_slots(k, kv_kbits, row_dims=2)
-        v = fops.fake_quant_slots(v, kv_kbits, row_dims=2)
-    ps = pc["k"].shape[1]
-    b = x.shape[0]
-    mp = page_table.shape[1]
-    cols_raw = pos // ps
-    cols = jnp.clip(cols_raw, 0, mp - 1)
-    pidx = page_table[jnp.arange(b), cols]                 # (B,)
-    # an out-of-table position must NOT clamp into the last allocated
-    # page (that would overwrite a live slot in place) — route it to
-    # the trash page exactly like a dead lane
-    ok = (pidx > 0) & (cols_raw < mp)
-    if write_mask is not None:
-        ok = ok & write_mask
-    pidx = jnp.where(ok, pidx, 0)                          # trash page
-    off = pos % ps
-    pk = pc["k"].at[pidx, off].set(k[:, 0])
-    pv = pc["v"].at[pidx, off].set(v[:, 0])
-    if paged_kernel:
-        from repro.kernels.paged_attn import ops as pops
+            k = fops.fake_quant_slots(k, kv_kbits, row_dims=2)
+            v = fops.fake_quant_slots(v, kv_kbits, row_dims=2)
+        ps = pc["k"].shape[1]
+        b = x.shape[0]
+        mp = page_table.shape[1]
+        cols_raw = pos // ps
+        cols = jnp.clip(cols_raw, 0, mp - 1)
+        pidx = page_table[jnp.arange(b), cols]             # (B,)
+        # an out-of-table position must NOT clamp into the last
+        # allocated page (that would overwrite a live slot in place) —
+        # route it to the trash page exactly like a dead lane
+        ok = (pidx > 0) & (cols_raw < mp)
+        if write_mask is not None:
+            ok = ok & write_mask
+        pidx = jnp.where(ok, pidx, 0)                      # trash page
+        off = pos % ps
+        pk = pc["k"].at[pidx, off].set(k[:, 0])
+        pv = pc["v"].at[pidx, off].set(v[:, 0])
+    with jax.named_scope(spans.ATTN_READ):
+        if paged_kernel:
+            from repro.kernels.paged_attn import ops as pops
 
-        out = pops.paged_attention(q[:, 0], pk, pv, page_table,
-                                   pos)[:, None]
-    else:
-        kb = gather_pages(pk, page_table)
-        vb = gather_pages(pv, page_table)
-        out = attention(
-            q, kb, vb, causal=False, kv_valid_len=pos + 1,
-            q_positions=ppos
-        )
+            out = pops.paged_attention(q[:, 0], pk, pv, page_table,
+                                       pos)[:, None]
+        else:
+            kb = gather_pages(pk, page_table)
+            vb = gather_pages(pv, page_table)
+            out = attention(
+                q, kb, vb, causal=False, kv_valid_len=pos + 1,
+                q_positions=ppos
+            )
     out = jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
     return out, {"k": pk, "v": pv}
 
@@ -401,18 +404,22 @@ def block_decode_paged(x, bp, pc, cfg: ModelConfig, page_table, pos,
     new_pc: dict[str, Any] = {}
     for j, (mixer, mlp_kind) in enumerate(sublayer_kinds(cfg)):
         assert mixer == "attn", "paged decode is attention-only"
-        h = rms_norm(x, bp[f"norm1_{j}"])
-        mixed, c = _attn_decode_paged(
-            h, bp[f"attn_{j}"], cfg, {"k": pc[f"k_{j}"], "v": pc[f"v_{j}"]},
-            page_table, pos, kv_kbits, write_mask, paged_kernel,
-        )
+        with jax.named_scope(spans.ATTN):
+            h = rms_norm(x, bp[f"norm1_{j}"])
+            mixed, c = _attn_decode_paged(
+                h, bp[f"attn_{j}"], cfg,
+                {"k": pc[f"k_{j}"], "v": pc[f"v_{j}"]},
+                page_table, pos, kv_kbits, write_mask, paged_kernel,
+            )
         new_pc[f"k_{j}"], new_pc[f"v_{j}"] = c["k"], c["v"]
-        if cfg.parallel_block:
-            x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg, decode=True)
-        else:
-            x = x + mixed
-            h2 = rms_norm(x, bp[f"norm2_{j}"])
-            x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)
+        with jax.named_scope(spans.MLP):
+            if cfg.parallel_block:
+                x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg,
+                                         decode=True)
+            else:
+                x = x + mixed
+                h2 = rms_norm(x, bp[f"norm2_{j}"])
+                x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)
     return x, new_pc
 
 
@@ -540,8 +547,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool, page_table, tokens,
                                   kv_kbits, write_mask, paged_kernel)
 
     x, new_pool = lax.scan(body, x, (params["layers"], pool))
-    x = rms_norm(x, params["final_norm"])
-    return _lm_head(cfg, params, x)[:, 0], new_pool
+    with jax.named_scope(spans.HEAD):
+        x = rms_norm(x, params["final_norm"])
+        return _lm_head(cfg, params, x)[:, 0], new_pool
 
 
 def paged_pool_specs(cfg: ModelConfig, n_pages: int, page_size: int) -> dict:

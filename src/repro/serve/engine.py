@@ -95,7 +95,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.core.ese.meter import MeterConfig, SustainabilityMeter
 from repro.core.ese.records import EnergyReport
@@ -316,37 +318,41 @@ def build_paged_decode_loop(mcfg: ModelConfig, *, eos_id: int | None = None,
                     return c
                 return jax.lax.while_loop(adm_cond, adm_body, c)
 
-            return jax.lax.fori_loop(0, B, lane_fix, c)
+            with jax.named_scope(spans.LOOP_ADMIT):
+                return jax.lax.fori_loop(0, B, lane_fix, c)
 
         def cond(c):
             return c["alive"].any()
 
         def body(c):
             # 1. on-demand allocation for this step's KV writes
-            cols = jnp.clip(c["pos"] // page_size, 0, mp - 1)
-            need = c["alive"] & (c["pt"][rows_b, cols] < 0)
-            pt, ft, m = paging.alloc_pages(c["pt"], c["fs"], c["ft"],
-                                           need, cols)
-            ppr = c["ppr"].at[jnp.where(need, c["lane"], R)].add(
-                need.astype(jnp.int32))
-            in_use = c["in_use"] + m
-            peak = jnp.maximum(c["peak"], in_use)
+            with jax.named_scope(spans.LOOP_ALLOC):
+                cols = jnp.clip(c["pos"] // page_size, 0, mp - 1)
+                need = c["alive"] & (c["pt"][rows_b, cols] < 0)
+                pt, ft, m = paging.alloc_pages(c["pt"], c["fs"], c["ft"],
+                                               need, cols)
+                ppr = c["ppr"].at[jnp.where(need, c["lane"], R)].add(
+                    need.astype(jnp.int32))
+                in_use = c["in_use"] + m
+                peak = jnp.maximum(c["peak"], in_use)
             # 2. one token for every lane
             logits, pool = model.decode_step_paged(
                 mcfg, params, c["pool"], pt, c["tok"], c["pos"],
                 kv_kbits=kv_kbits, write_mask=c["alive"],
                 paged_kernel=paged_kernel)
-            nxt = greedy_sample(logits)
+            with jax.named_scope(spans.HEAD):
+                nxt = greedy_sample(logits)
             # 3. emit into the lane's *request* row
-            rr = jnp.where(c["alive"], c["lane"], R)
-            out = c["out"].at[
-                rr, jnp.clip(c["n_out"][rr], 0, out_cap - 1)].set(nxt)
-            n_out = c["n_out"].at[rr].add(c["alive"].astype(jnp.int32))
-            alive = c["alive"] & (n_out[c["lane"]] < mn1[c["lane"]])
-            if eos_id is not None:
-                alive = alive & (nxt != eos_id)
-            tok = jnp.where(alive, nxt, c["tok"])
-            pos = c["pos"] + alive.astype(jnp.int32)
+            with jax.named_scope(spans.LOOP_EMIT):
+                rr = jnp.where(c["alive"], c["lane"], R)
+                out = c["out"].at[
+                    rr, jnp.clip(c["n_out"][rr], 0, out_cap - 1)].set(nxt)
+                n_out = c["n_out"].at[rr].add(c["alive"].astype(jnp.int32))
+                alive = c["alive"] & (n_out[c["lane"]] < mn1[c["lane"]])
+                if eos_id is not None:
+                    alive = alive & (nxt != eos_id)
+                tok = jnp.where(alive, nxt, c["tok"])
+                pos = c["pos"] + alive.astype(jnp.int32)
             c = dict(c, pool=pool, pt=pt, ft=ft, tok=tok, pos=pos,
                      alive=alive, out=out, n_out=n_out, in_use=in_use,
                      peak=peak, ppr=ppr, steps=c["steps"] + 1)
@@ -563,17 +569,18 @@ class ServeEngine:
         bucket boundary.  Paged mode: each super-bucket drains up to
         ``max_batch + stage_depth`` requests through in-loop admission.
         Returns {rid: tokens} for every completed request."""
-        while self._pending:
-            self._reap_expired()
-            if not self._pending:
-                break
-            if self.paged and self.flash is not None:
-                self._serve_flash_bucket()
-            elif self.paged:
-                self._serve_paged_bucket()
-            else:
-                self._serve_bucket(self._next_bucket())
-        return dict(self._results)
+        with TraceAnnotation(spans.RUN, R=len(self._pending)):
+            while self._pending:
+                self._reap_expired()
+                if not self._pending:
+                    break
+                if self.paged and self.flash is not None:
+                    self._serve_flash_bucket()
+                elif self.paged:
+                    self._serve_paged_bucket()
+                else:
+                    self._serve_bucket(self._next_bucket())
+            return dict(self._results)
 
     def _bucket_geometry(self, reqs: list[Request]):
         """Shared bucket prep for both cache layouts: per-request
@@ -607,64 +614,73 @@ class ServeEngine:
     # -- one bucket ----------------------------------------------------------
     def _serve_bucket(self, bucket: list[Request]) -> None:
         B = len(bucket)
-        lens, S, max_new, horizon, out_cap, prompts = \
-            self._bucket_geometry(bucket)
-        ragged = self._ragged_ok and bool((lens != S).any())
-        batch = {"tokens": jnp.asarray(prompts)}
-        if self.mcfg.family == "audio":
-            batch["enc_embeds"] = jnp.zeros(
-                (B, self.mcfg.encoder_seq, self.mcfg.d_model), jnp.bfloat16
-            )
+        with TraceAnnotation(spans.ADMIT):
+            lens, S, max_new, horizon, out_cap, prompts = \
+                self._bucket_geometry(bucket)
+            ragged = self._ragged_ok and bool((lens != S).any())
+            batch = {"tokens": jnp.asarray(prompts)}
+            if self.mcfg.family == "audio":
+                batch["enc_embeds"] = jnp.zeros(
+                    (B, self.mcfg.encoder_seq, self.mcfg.d_model),
+                    jnp.bfloat16)
+            pos0 = jnp.asarray(lens)
+            mn = jnp.asarray(max_new)
         t_bucket0 = time.time()
-        tok0, cache = self._prefill(
-            self.params, batch, jnp.asarray(lens) if ragged else None)
-        self.stats.prefills += 1
-        cache = self._grow_cache(cache, B, S + out_cap)
-        # the contiguous layout holds every lane at bucket-max for the
-        # whole bucket (the numbers the paged layout beats — bench_serve
-        # gates both ratios).  Symmetric with the paged side: peak =
-        # the actual horizon (resident model), pool = the pow2-rounded
-        # allocation (physical) — never-writable rounding tail excluded
-        # from peak on both layouts.
-        self.stats.kv_bytes_peak = max(self.stats.kv_bytes_peak,
-                                       self._contig_cache_bytes(B, S + horizon))
-        self.stats.kv_bytes_pool = max(self.stats.kv_bytes_pool,
-                                       self._contig_cache_bytes(B, S + out_cap))
-        bucket_kv_frac = 0
-        if self.kv_frac_kbits is not None:
-            cache, bucket_kv_frac = self._frac_cache(cache, B, S + horizon)
-        pos0 = jnp.asarray(lens)
-        mn = jnp.asarray(max_new)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding
+        with TraceAnnotation(spans.PREFILL, R=B, S=S):
+            tok0, cache = self._prefill(
+                self.params, batch, jnp.asarray(lens) if ragged else None)
+            self.stats.prefills += 1
+            cache = self._grow_cache(cache, B, S + out_cap)
+            # the contiguous layout holds every lane at bucket-max for
+            # the whole bucket (the numbers the paged layout beats —
+            # bench_serve gates both ratios).  Symmetric with the paged
+            # side: peak = the actual horizon (resident model), pool =
+            # the pow2-rounded allocation (physical) — never-writable
+            # rounding tail excluded from peak on both layouts.
+            self.stats.kv_bytes_peak = max(
+                self.stats.kv_bytes_peak,
+                self._contig_cache_bytes(B, S + horizon))
+            self.stats.kv_bytes_pool = max(
+                self.stats.kv_bytes_pool,
+                self._contig_cache_bytes(B, S + out_cap))
+            bucket_kv_frac = 0
+            if self.kv_frac_kbits is not None:
+                cache, bucket_kv_frac = self._frac_cache(cache, B,
+                                                         S + horizon)
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding
 
-            from repro.sharding import rules
+                from repro.sharding import rules
 
-            specs = model.cache_specs(self.mcfg, B, S + out_cap)
-            cache = jax.device_put(
-                cache, rules.cache_shardings(specs, self.mesh, B))
-            vec, _ = rules.serve_loop_spec(self.mesh, B)
-            sh = NamedSharding(self.mesh, vec)
-            tok0, pos0, mn = jax.device_put((tok0, pos0, mn), (sh, sh, sh))
+                specs = model.cache_specs(self.mcfg, B, S + out_cap)
+                cache = jax.device_put(
+                    cache, rules.cache_shardings(specs, self.mesh, B))
+                vec, _ = rules.serve_loop_spec(self.mesh, B)
+                sh = NamedSharding(self.mesh, vec)
+                tok0, pos0, mn = jax.device_put((tok0, pos0, mn),
+                                                (sh, sh, sh))
         # first token is ready here: TTFT measured from each request's
         # own submit time (a sync, not a transfer — the value stays on
         # device and rides the output buffer)
-        tok0.block_until_ready()
+        with TraceAnnotation(spans.FIRST_TOKEN_SYNC):
+            tok0.block_until_ready()
         t_first = time.time()
         for r in bucket:
             r.t_first = t_first
             self.stats.ttft_s.append(t_first - r.t_submit)
-        loop = self._get_loop(ragged, out_cap)
-        out, n_out, steps, _ = loop(self.params, cache, tok0, pos0, mn)
-        # the decode phase's single host transfer
-        out_np, n_np, steps_np = jax.device_get((out, n_out, steps))
-        self.stats.host_syncs += 1
-        now = time.time()
-        self.stats.decode_steps += int(steps_np)
-        self._note_steps(now - t_first, int(steps_np))
-        self.stats.decode_s += now - t_first
-        self._finish_bucket(bucket, out_np, n_np, now, now - t_bucket0,
-                            lambda i: bucket_kv_frac // B)
+        with TraceAnnotation(spans.LOOP, out_cap=out_cap):
+            loop = self._get_loop(ragged, out_cap)
+            out, n_out, steps, _ = loop(self.params, cache, tok0, pos0, mn)
+            # the decode phase's single host transfer
+            out_np, n_np, steps_np = jax.device_get((out, n_out, steps))
+        with TraceAnnotation(spans.FINISH, steps=int(steps_np)):
+            self.stats.host_syncs += 1
+            now = time.time()
+            self.stats.decode_steps += int(steps_np)
+            self._note_steps(now - t_first, int(steps_np))
+            self.stats.decode_s += now - t_first
+            self._finish_bucket(bucket, out_np, n_np, now, now - t_bucket0,
+                                lambda i: bucket_kv_frac // B)
 
     def _finish_bucket(self, reqs, out_np, n_np, now, bucket_dt,
                        kv_bytes_fn) -> None:
@@ -715,87 +731,83 @@ class ServeEngine:
         carries its own pages' FRAC bytes, and ``stats.kv_bytes_peak``
         tracks the true high-water mark of concurrently live pages.
         """
-        nb = min(self.max_batch, len(self._pending))
-        reqs = self._pending[: nb + self.stage_depth]
-        staged_n = len(reqs) - nb
-        lens, S, max_new, _, out_cap, prompts = self._bucket_geometry(reqs)
+        with TraceAnnotation(spans.ADMIT):
+            nb = min(self.max_batch, len(self._pending))
+            reqs = self._pending[: nb + self.stage_depth]
+            staged_n = len(reqs) - nb
+            lens, S, max_new, _, out_cap, prompts = \
+                self._bucket_geometry(reqs)
+            # pow2=True bounds the compiled loop variants (pool + table
+            # shapes round up; spare pages idle on the free stack) — B
+            # and Q are already bounded by max_batch / stage_depth,
+            # out_cap by its own rounding
+            plan = paging.plan_pages(lens, max_new, nb, self.page_size,
+                                     pow2=True)
+            full_table = np.concatenate([plan.page_table, plan.staged_pt])
+            pi, oi = paging.pool_scatter_indices(
+                full_table, lens, S, plan.n_pages, self.page_size)
+            pool_specs = model.paged_pool_specs(
+                self.mcfg, plan.n_pages, self.page_size)
+            pi, oi = jnp.asarray(pi), jnp.asarray(oi)
+            pt = jnp.asarray(plan.page_table)
+            spt = jnp.asarray(plan.staged_pt)
+            fs = jnp.asarray(plan.free_stack)
+            pos0 = jnp.asarray(lens[:nb])
+            slen = jnp.asarray(lens[nb:])
+            mn = jnp.asarray(max_new)
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding
+
+                from repro.sharding import rules
+
+                rep = NamedSharding(self.mesh,
+                                    rules.serve_paged_spec(self.mesh))
+                pt, spt, fs, pos0, slen, mn = jax.device_put(
+                    (pt, spt, fs, pos0, slen, mn), (rep,) * 6)
         t_bucket0 = time.time()
-        tok0, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompts)}, jnp.asarray(lens))
-        self.stats.prefills += 1
-        if self.kv_frac_kbits is not None:
-            # same slot-granular fake-quant as the contiguous FRAC tier
-            # (one scale per (K, hd) row) — page layout changes where
-            # bytes LIVE, never a lane's numerics
-            cache = _frac_kv_rows(cache, self.kv_frac_kbits)
-        # pow2=True bounds the compiled loop variants (pool + table
-        # shapes round up; spare pages idle on the free stack) — B and
-        # Q are already bounded by max_batch / stage_depth, out_cap by
-        # its own rounding
-        plan = paging.plan_pages(lens, max_new, nb, self.page_size,
-                                 pow2=True)
-        full_table = np.concatenate([plan.page_table, plan.staged_pt])
-        pi, oi = paging.pool_scatter_indices(
-            full_table, lens, S, plan.n_pages, self.page_size)
-        pool_specs = model.paged_pool_specs(
-            self.mcfg, plan.n_pages, self.page_size)
-        pi, oi = jnp.asarray(pi), jnp.asarray(oi)
-        pool = jax.tree.map(
-            lambda spec, leaf: paging.fill_pool(
-                jnp.zeros(spec.shape, leaf.dtype), leaf, pi, oi),
-            pool_specs, cache, is_leaf=is_leaf_spec)
-        del cache                   # the pool holds the prompt KV now
-        pt = jnp.asarray(plan.page_table)
-        spt = jnp.asarray(plan.staged_pt)
-        fs = jnp.asarray(plan.free_stack)
-        pos0 = jnp.asarray(lens[:nb])
-        slen = jnp.asarray(lens[nb:])
-        mn = jnp.asarray(max_new)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding
-
-            from repro.sharding import rules
-
-            pool = jax.device_put(
-                pool, rules.cache_shardings(pool_specs, self.mesh, nb))
-            rep = NamedSharding(self.mesh, rules.serve_paged_spec(self.mesh))
-            pt, spt, fs, pos0, slen, mn = jax.device_put(
-                (pt, spt, fs, pos0, slen, mn), (rep,) * 6)
-        tok0.block_until_ready()
+        with TraceAnnotation(spans.PREFILL, R=len(reqs), S=S):
+            tok0, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(prompts)},
+                jnp.asarray(lens))
+            self.stats.prefills += 1
+            if self.kv_frac_kbits is not None:
+                # same slot-granular fake-quant as the contiguous FRAC
+                # tier (one scale per (K, hd) row) — page layout changes
+                # where bytes LIVE, never a lane's numerics
+                cache = _frac_kv_rows(cache, self.kv_frac_kbits)
+        with TraceAnnotation(spans.POOL_FILL, pages=plan.n_pages):
+            pool = jax.tree.map(
+                lambda spec, leaf: paging.fill_pool(
+                    jnp.zeros(spec.shape, leaf.dtype), leaf, pi, oi),
+                pool_specs, cache, is_leaf=is_leaf_spec)
+            del cache               # the pool holds the prompt KV now
+            if self.mesh is not None:
+                pool = jax.device_put(
+                    pool, rules.cache_shardings(pool_specs, self.mesh, nb))
+        with TraceAnnotation(spans.FIRST_TOKEN_SYNC):
+            tok0.block_until_ready()
         t_first = time.time()
         for r in reqs:
             r.t_first = t_first
             self.stats.ttft_s.append(t_first - r.t_submit)
-        loop = self._get_paged_loop(out_cap)
-        out, n_out, steps, peak, ppr, adm, _ = loop(
-            self.params, pool, pt, fs, np.int32(plan.free_top),
-            tok0[:nb], pos0, tok0[nb:], slen, spt, mn)
-        # the super-bucket's single host transfer
-        out_np, n_np, steps_np, peak_np, ppr_np, adm_np = jax.device_get(
-            (out, n_out, steps, peak, ppr, adm))
-        self.stats.host_syncs += 1
-        now = time.time()
-        self.stats.decode_steps += int(steps_np)
-        self._note_steps(now - t_first, int(steps_np))
-        self.stats.decode_s += now - t_first
-        self.stats.admissions += int(adm_np)
-        assert int(adm_np) == staged_n, "stage queue not drained in-loop"
-        self._note_attn_transient(nb, plan.page_table.shape[1])
-        page_full_b, page_frac_b = self._page_bytes()
-        self.stats.kv_pages_peak = max(self.stats.kv_pages_peak,
-                                       int(peak_np))
-        self.stats.kv_bytes_peak = max(self.stats.kv_bytes_peak,
-                                       int(peak_np) * page_full_b)
-        self.stats.kv_bytes_pool = max(self.stats.kv_bytes_pool,
-                                       plan.n_pages * page_full_b)
-        kv_bytes_fn = lambda i: 0
-        if self.kv_frac_kbits is not None:
-            pages_total = int(ppr_np.sum())
-            self.stats.kv_bytes_full += pages_total * page_full_b
-            self.stats.kv_bytes_frac += pages_total * page_frac_b
-            kv_bytes_fn = lambda i: int(ppr_np[i]) * page_frac_b
-        self._finish_bucket(reqs, out_np, n_np, now, now - t_bucket0,
-                            kv_bytes_fn)
+        with TraceAnnotation(spans.LOOP, out_cap=out_cap):
+            loop = self._get_paged_loop(out_cap)
+            out, n_out, steps, peak, ppr, adm, _ = loop(
+                self.params, pool, pt, fs, np.int32(plan.free_top),
+                tok0[:nb], pos0, tok0[nb:], slen, spt, mn)
+            # the super-bucket's single host transfer
+            out_np, n_np, steps_np, peak_np, ppr_np, adm_np = \
+                jax.device_get((out, n_out, steps, peak, ppr, adm))
+        with TraceAnnotation(spans.FINISH, steps=int(steps_np)):
+            self.stats.host_syncs += 1
+            now = time.time()
+            self.stats.decode_steps += int(steps_np)
+            self._note_steps(now - t_first, int(steps_np))
+            self.stats.decode_s += now - t_first
+            self.stats.admissions += int(adm_np)
+            assert int(adm_np) == staged_n, "stage queue not drained in-loop"
+            self._book_pages(nb, plan, peak_np, ppr_np, reqs, out_np, n_np,
+                             now, now - t_bucket0)
 
     # -- flash-oversubscribed super-bucket -------------------------------------
     def _serve_flash_bucket(self) -> None:
@@ -812,34 +824,40 @@ class ServeEngine:
         exactly the non-oversubscribed path."""
         from repro.serve import flash_tier as ftier
 
-        nb = min(self.max_batch, len(self._pending))
-        cand = self._pending[: nb + self.stage_depth]
-        staged = cand[nb:]
-        # LRU victim order over the cold staged prompts (their KV is
-        # untouched since submit), then a greedy capacity dry-run
-        order = ftier.pick_victims(
-            [(i, r.t_submit) for i, r in enumerate(staged)])
-        sizes_all: list[int] = []
-        fit: list[int] = []
-        for i in order:
-            sizes = self._spill_page_sizes(len(staged[i].prompt))
-            if self.flash.would_fit(sizes_all + sizes):
-                sizes_all += sizes
-                fit.append(i)
+        with TraceAnnotation(spans.ADMIT):
+            nb = min(self.max_batch, len(self._pending))
+            cand = self._pending[: nb + self.stage_depth]
+            staged = cand[nb:]
+            # LRU victim order over the cold staged prompts (their KV is
+            # untouched since submit), then a greedy capacity dry-run
+            order = ftier.pick_victims(
+                [(i, r.t_submit) for i, r in enumerate(staged)])
+            sizes_all: list[int] = []
+            fit: list[int] = []
+            for i in order:
+                sizes = self._spill_page_sizes(len(staged[i].prompt))
+                if self.flash.would_fit(sizes_all + sizes):
+                    sizes_all += sizes
+                    fit.append(i)
+            if fit:
+                reqs = cand[:nb] + [staged[i] for i in sorted(fit)]
+                lens, S, max_new, _, out_cap, prompts = \
+                    self._bucket_geometry(reqs)
         if not fit:
             # exhausted tier (or nothing staged): exactly PR-5 behavior
             self._serve_paged_bucket()
             return
-        reqs = cand[:nb] + [staged[i] for i in sorted(fit)]
-        lens, S, max_new, _, out_cap, prompts = self._bucket_geometry(reqs)
         t_bucket0 = time.time()
-        tok0, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompts)}, jnp.asarray(lens))
-        self.stats.prefills += 1
-        if self.kv_frac_kbits is not None:
-            cache = _frac_kv_rows(cache, self.kv_frac_kbits)
-        leaves, treedef = jax.tree.flatten(cache)
-        tok0_np = np.asarray(jax.device_get(tok0))
+        with TraceAnnotation(spans.PREFILL, R=len(reqs), S=S):
+            tok0, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(prompts)},
+                jnp.asarray(lens))
+            self.stats.prefills += 1
+            if self.kv_frac_kbits is not None:
+                cache = _frac_kv_rows(cache, self.kv_frac_kbits)
+            leaves, treedef = jax.tree.flatten(cache)
+        with TraceAnnotation(spans.FIRST_TOKEN_SYNC):
+            tok0_np = np.asarray(jax.device_get(tok0))
         t_first = time.time()
         t0map = {r.rid: int(tok0_np[i]) for i, r in enumerate(reqs)}
         # spill the staged prompt KV straight from the prefill transient
@@ -849,13 +867,14 @@ class ServeEngine:
         staged_reqs = reqs[nb:]
         queue: list[Request] = []
         if staged_reqs:
-            staged_np = jax.device_get([l[:, nb:] for l in leaves])
-            self.stats.host_syncs += 1       # oversubscription overhead
-            for j, r in enumerate(staged_reqs):
-                if self._spill_request(r, staged_np, j):
-                    queue.append(r)
-                else:
-                    self.flash.discard(r.rid)
+            with TraceAnnotation(spans.SPILL, R=len(staged_reqs)):
+                staged_np = jax.device_get([l[:, nb:] for l in leaves])
+                self.stats.host_syncs += 1   # oversubscription overhead
+                for j, r in enumerate(staged_reqs):
+                    if self._spill_request(r, staged_np, j):
+                        queue.append(r)
+                    else:
+                        self.flash.discard(r.rid)
         for r in reqs[:nb] + queue:          # the actually-served set
             r.t_first = t_first
             self.stats.ttft_s.append(t_first - r.t_submit)
@@ -873,7 +892,8 @@ class ServeEngine:
             if not queue:
                 break
             wave, queue = queue[: self.max_batch], queue[self.max_batch:]
-            wave_np = self._fault_in_wave(wave, leaves, t0map)
+            with TraceAnnotation(spans.FAULT_IN, R=len(wave)):
+                wave_np = self._fault_in_wave(wave, leaves, t0map)
             self._serve_wave(wave, wave_np, treedef, t0map)
         # flash I/O energy: device-level ops at wear.py prices plus the
         # spilled bytes' recycled-flash embodied residency share
@@ -1007,40 +1027,53 @@ class ServeEngine:
         queue (Q=0 statically skips the admission machinery)."""
         ps = self.page_size
         t_wave0 = time.time()
-        lens = np.asarray([len(r.prompt) for r in wreqs], np.int32)
-        S_w = int(lens.max())
-        max_new = np.asarray([self._deadline_max_new(r) for r in wreqs],
-                             np.int32)
-        out_cap = 1 << (int(max_new.max()) - 1).bit_length()
-        plan = paging.plan_pages(lens, max_new, len(wreqs), ps, pow2=True)
-        pi, oi = paging.pool_scatter_indices(
-            plan.page_table, lens, S_w, plan.n_pages, ps)
-        pool_specs = model.paged_pool_specs(self.mcfg, plan.n_pages, ps)
-        pi, oi = jnp.asarray(pi), jnp.asarray(oi)
-        cache_w = jax.tree.unflatten(
-            treedef, [jnp.asarray(l[:, :, :S_w]) for l in wave_leaves])
-        pool = jax.tree.map(
-            lambda spec, leaf: paging.fill_pool(
-                jnp.zeros(spec.shape, leaf.dtype), leaf, pi, oi),
-            pool_specs, cache_w, is_leaf=is_leaf_spec)
-        tok0 = jnp.asarray([t0map[r.rid] for r in wreqs], jnp.int32)
-        loop = self._get_paged_loop(out_cap)
-        out, n_out, steps, peak, ppr, adm, _ = loop(
-            self.params, pool, jnp.asarray(plan.page_table),
-            jnp.asarray(plan.free_stack), np.int32(plan.free_top),
-            tok0, jnp.asarray(lens), jnp.zeros((0,), jnp.int32),
-            jnp.zeros((0,), jnp.int32), jnp.asarray(plan.staged_pt),
-            jnp.asarray(max_new))
-        out_np, n_np, steps_np, peak_np, ppr_np, adm_np = jax.device_get(
-            (out, n_out, steps, peak, ppr, adm))
-        self.stats.host_syncs += 1
-        now = time.time()
-        self.stats.decode_steps += int(steps_np)
-        self._note_steps(now - t_wave0, int(steps_np))
-        self.stats.decode_s += now - t_wave0
-        assert int(adm_np) == 0
-        self.stats.oversub_waves += 1
-        self._note_attn_transient(len(wreqs), plan.page_table.shape[1])
+        with TraceAnnotation(spans.ADMIT):
+            lens = np.asarray([len(r.prompt) for r in wreqs], np.int32)
+            S_w = int(lens.max())
+            max_new = np.asarray(
+                [self._deadline_max_new(r) for r in wreqs], np.int32)
+            out_cap = 1 << (int(max_new.max()) - 1).bit_length()
+            plan = paging.plan_pages(lens, max_new, len(wreqs), ps,
+                                     pow2=True)
+            pi, oi = paging.pool_scatter_indices(
+                plan.page_table, lens, S_w, plan.n_pages, ps)
+            pool_specs = model.paged_pool_specs(self.mcfg, plan.n_pages, ps)
+            pi, oi = jnp.asarray(pi), jnp.asarray(oi)
+            tok0 = jnp.asarray([t0map[r.rid] for r in wreqs], jnp.int32)
+        with TraceAnnotation(spans.POOL_FILL, pages=plan.n_pages):
+            cache_w = jax.tree.unflatten(
+                treedef, [jnp.asarray(l[:, :, :S_w]) for l in wave_leaves])
+            pool = jax.tree.map(
+                lambda spec, leaf: paging.fill_pool(
+                    jnp.zeros(spec.shape, leaf.dtype), leaf, pi, oi),
+                pool_specs, cache_w, is_leaf=is_leaf_spec)
+        with TraceAnnotation(spans.LOOP, out_cap=out_cap):
+            loop = self._get_paged_loop(out_cap)
+            out, n_out, steps, peak, ppr, adm, _ = loop(
+                self.params, pool, jnp.asarray(plan.page_table),
+                jnp.asarray(plan.free_stack), np.int32(plan.free_top),
+                tok0, jnp.asarray(lens), jnp.zeros((0,), jnp.int32),
+                jnp.zeros((0,), jnp.int32), jnp.asarray(plan.staged_pt),
+                jnp.asarray(max_new))
+            out_np, n_np, steps_np, peak_np, ppr_np, adm_np = \
+                jax.device_get((out, n_out, steps, peak, ppr, adm))
+        with TraceAnnotation(spans.FINISH, steps=int(steps_np)):
+            self.stats.host_syncs += 1
+            now = time.time()
+            self.stats.decode_steps += int(steps_np)
+            self._note_steps(now - t_wave0, int(steps_np))
+            self.stats.decode_s += now - t_wave0
+            assert int(adm_np) == 0
+            self.stats.oversub_waves += 1
+            self._book_pages(len(wreqs), plan, peak_np, ppr_np, wreqs,
+                             out_np, n_np, now, now - t_wave0)
+
+    def _book_pages(self, nb, plan, peak_np, ppr_np, reqs, out_np, n_np,
+                    now, dt) -> None:
+        """Paged bucket tail: page and byte high-water marks, each
+        request's FRAC bytes from its own allocated pages, then
+        ``_finish_bucket``."""
+        self._note_attn_transient(nb, plan.page_table.shape[1])
         page_full_b, page_frac_b = self._page_bytes()
         self.stats.kv_pages_peak = max(self.stats.kv_pages_peak,
                                        int(peak_np))
@@ -1054,8 +1087,7 @@ class ServeEngine:
             self.stats.kv_bytes_full += pages_total * page_full_b
             self.stats.kv_bytes_frac += pages_total * page_frac_b
             kv_bytes_fn = lambda i: int(ppr_np[i]) * page_frac_b
-        self._finish_bucket(wreqs, out_np, n_np, now, now - t_wave0,
-                            kv_bytes_fn)
+        self._finish_bucket(reqs, out_np, n_np, now, dt, kv_bytes_fn)
 
     def _page_bytes(self) -> tuple[int, int]:
         """(full, frac) resident bytes per allocated page, summed over
