@@ -103,7 +103,7 @@ def phase_device(ctx) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _paged_fixture(rng, B, H, K, hd, ps, positions, dtype):
+def _paged_fixture(rng, B, H, K, hd, ps, positions, dtype, layers=3):
     import jax.numpy as jnp
     import numpy as np
 
@@ -116,8 +116,9 @@ def _paged_fixture(rng, B, H, K, hd, ps, positions, dtype):
     for b, n in enumerate(pages):
         table[b, :n] = ids[used:used + n]
         used += n
-    pk = rng.standard_normal((n_pages, ps, K, hd), np.float32)
-    pv = rng.standard_normal((n_pages, ps, K, hd), np.float32)
+    # the stacked pool as the engine stores it: (layers, P, ps, K*hd)
+    pk = rng.standard_normal((layers, n_pages, ps, K * hd), np.float32)
+    pv = rng.standard_normal((layers, n_pages, ps, K * hd), np.float32)
     q = rng.standard_normal((B, H, hd), np.float32)
     return (jnp.asarray(q, dtype), jnp.asarray(pk, dtype),
             jnp.asarray(pv, dtype), jnp.asarray(table),
@@ -125,8 +126,9 @@ def _paged_fixture(rng, B, H, K, hd, ps, positions, dtype):
 
 
 def kernel_paged_attention(ctx) -> None:
-    """llama3.2-3b decode shapes; the trash page is NaN-poisoned and is
-    DMA'd for every lane whose last chunk has unallocated columns."""
+    """llama3.2-3b decode shapes, the last layer of a 3-layer stacked
+    pool read in place; the trash page is NaN-poisoned and is DMA'd
+    for every lane whose last chunk has unallocated columns."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -141,31 +143,33 @@ def kernel_paged_attention(ctx) -> None:
     q, pk, pv, table, pos = _paged_fixture(
         np.random.default_rng(ctx["seed"]), len(positions), H, K, hd, ps,
         positions, jnp.bfloat16)
-    pk = pk.at[0].set(jnp.nan)
-    pv = pv.at[0].set(jnp.nan)
+    pk = pk.at[:, 0].set(jnp.nan)
+    pv = pv.at[:, 0].set(jnp.nan)
+    layer = jnp.int32(pk.shape[0] - 1)
     fused = jax.jit(lambda *a: pops.paged_attention(*a, mode="pallas"))
-    out, t_cold = timed(fused, q, pk, pv, table, pos)
-    out, t_warm = timed(fused, q, pk, pv, table, pos)
+    out, t_cold = timed(fused, q, pk, pv, table, pos, layer)
+    out, t_warm = timed(fused, q, pk, pv, table, pos, layer)
 
     @jax.jit
-    def oracle(q, pk, pv, table, pos):
-        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-        kb, vb = gather_pages(f32(pk), table), gather_pages(f32(pv), table)
-        return attention(f32(q)[:, None], kb, vb, causal=False,
+    def oracle(q, pk, pv, table, pos, layer):
+        kv = [gather_pages(p.astype(jnp.float32), table, layer).reshape(
+            q.shape[0], -1, K, hd) for p in (pk, pv)]
+        return attention(q.astype(jnp.float32)[:, None], *kv, causal=False,
                          kv_valid_len=pos + 1,
                          q_positions=pos[:, None])[:, 0]
 
-    ref = oracle(q, pk, pv, table, pos)
+    ref = oracle(q, pk, pv, table, pos, layer)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
     # Tolerance: the fp32 oracle sees the same bf16 inputs; the kernel
     # differs only by rounding its (B, H, hd) output to bf16 (half an
     # ulp, 2^-9 of |out| <= max|v|) and its fp32 accumulation order.
     # 2^-7·max|v| leaves 4x headroom; a wrong page, slot mask or
     # position shifts outputs by O(max|v|).
-    vmax = float(jnp.max(jnp.abs(pv[1:].astype(jnp.float32))))
+    vmax = float(jnp.max(jnp.abs(pv[layer, 1:].astype(jnp.float32))))
     tol = 2.0 ** -7 * vmax
     log(f"[kernels] paged_attention B={len(positions)} H={H} K={K} "
-        f"hd={hd} ps={ps} pool={pk.shape[0]} pages table={table.shape} "
+        f"hd={hd} ps={ps} pool={pk.shape[:2]} layers x pages "
+        f"layer={int(layer)} table={table.shape} "
         f"max_abs_err={err:.3e} tol={tol:.3e} cold_s={t_cold:.3f} "
         f"warm_s={t_warm:.6f}")
     check(bool(jnp.isfinite(out).all()), "paged attention: non-finite "

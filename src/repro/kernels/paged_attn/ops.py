@@ -76,12 +76,13 @@ def _resolve_mode(mode: str | None) -> str:
     return mode
 
 
-def _paged_attention_jnp(q, pk, pv, page_table, pos, chunk):
+def _paged_attention_jnp(q, pk, pv, page_table, pos, layer, chunk):
     """Vectorized page walk: the kernel's ``fold_chunk`` vmapped over
-    lanes, one page-table chunk per step.  ``page_table`` width is a
-    multiple of ``chunk`` (padded by the dispatcher)."""
+    lanes, one page-table chunk per step, each chunk's pages gathered
+    from layer ``layer`` of the stacked pools.  ``page_table`` width is
+    a multiple of ``chunk`` (padded by the dispatcher)."""
     B, H, hd = q.shape
-    ps, K = pk.shape[1], pk.shape[2]
+    ps, K = pk.shape[2], pk.shape[3] // hd
     G = H // K
     T = chunk * ps
     max_pages = page_table.shape[1]
@@ -97,8 +98,8 @@ def _paged_attention_jnp(q, pk, pv, page_table, pos, chunk):
         entries = jax.lax.dynamic_slice_in_dim(
             page_table, first, chunk, axis=1)           # (B, chunk)
         pids = jnp.maximum(entries, 0)
-        k = pk[pids].reshape(B, T, K * hd)
-        v = pv[pids].reshape(B, T, K * hd)
+        k = pk[layer, pids].reshape(B, T, K * hd)
+        v = pv[layer, pids].reshape(B, T, K * hd)
         valid = ((first * ps + slot)[None, :] <= pos[:, None]) \
             & (entries[:, slot // ps] > 0)              # (B, T)
         return tuple(
@@ -121,13 +122,15 @@ PAGES_PER_CHUNK = 4      # pages folded per accumulator step: amortizes
 
 
 def paged_attention(q: jax.Array,           # (B, H, hd)
-                    pk: jax.Array,          # (P, ps, K, hd)
+                    pk: jax.Array,          # (L, P, ps, K*hd)
                     pv: jax.Array,
                     page_table: jax.Array,  # (B, max_pages)
                     pos: jax.Array,         # (B,)
+                    layer: jax.Array,       # int32 scalar
                     *, mode: str | None = None,
                     chunk: int = PAGES_PER_CHUNK) -> jax.Array:
-    """Fused paged GQA decode attention; (B, H, hd) in q.dtype.
+    """Fused paged GQA decode attention over layer ``layer`` of the
+    stacked lane-dense pools; (B, H, hd) in q.dtype.
 
     Any chunk size produces bit-identical output for a given mode
     (walked-but-masked pages are exact accumulator no-ops, and chunk
@@ -143,9 +146,10 @@ def paged_attention(q: jax.Array,           # (B, H, hd)
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)),
                              constant_values=-1)
     if mode == "jnp":
-        return _paged_attention_jnp(q, pk, pv, page_table, pos, chunk)
+        return _paged_attention_jnp(q, pk, pv, page_table, pos, layer,
+                                    chunk)
     return paged_decode.paged_attention(
-        q, pk, pv, page_table, pos, chunk=chunk,
+        q, pk, pv, page_table, pos, layer, chunk=chunk,
         interpret=(mode == "pallas_interpret"))
 
 

@@ -1,8 +1,11 @@
 """Pallas paged-attention decode kernel: attend through the page table.
 
-One grid step per lane.  The lane's page-table row and decode position
-arrive as scalar prefetch (SMEM); the K/V pools stay in HBM
-(``pl.ANY``).  The kernel walks ONLY the lane's
+One grid step per lane.  The lane's page-table row, its decode
+position and the layer index arrive as scalar prefetch (SMEM); the
+stacked K/V pools of every layer stay in HBM (``pl.ANY``) and are read
+in place: a page is the DMA of ``pool.at[layer, page]``, so neither the
+layer's pool nor the stack is ever sliced, reshaped or copied outside
+the kernel.  The kernel walks ONLY the lane's
 ``ceil((pos+1)/page_size)`` pages, ``chunk`` pages per step: each step
 DMAs the next chunk's pages into the other half of a double-buffered
 VMEM scratch while it folds the current chunk into a running
@@ -11,14 +14,15 @@ contiguous ``(B, max_pages * page_size, K, hd)`` cache that
 ``common.gather_pages`` materializes never exists, and VMEM holds two
 chunks of pages whatever the pool size.
 
-Layout: a pool leaf ``(P, ps, K, hd)`` is viewed as ``(P, ps, K*hd)``
-(a free reshape), so one page is one contiguous DMA and KV head ``h``
-is the lane-aligned column slice ``[h*hd, (h+1)*hd)`` of the chunk
-buffer.  The query arrives pre-scaled as ``(B, K, G, hd)``.
+Layout: a pool leaf is stored lane-dense as ``(L, P, ps, K*hd)``
+(``transformer.paged_pool_specs``), the kernel's own layout, so one
+page is one contiguous DMA and KV head ``h`` is the column slice
+``[h*hd, (h+1)*hd)`` of the chunk buffer.  The query arrives
+pre-scaled as ``(B, K, G, hd)``.
 
 Index math (mirrors serve/paging.py's layout):
 
-  logical slot s of lane b  ->  pool[page_table[b, s // ps], s % ps]
+  logical slot s of lane b  ->  pool[layer, page_table[b, s // ps], s % ps]
   pages to walk             ->  n = min(pos // ps + 1, max_pages)
   slot validity in page i   ->  (i * ps + arange(ps) <= pos)
                                  & (page_table[b, i] > 0)
@@ -100,9 +104,10 @@ def init_carry(K: int, G: int, hd: int, lead: tuple = ()):
         for _ in range(K))
 
 
-def _paged_attn_kernel(pt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
-                       kbuf, vbuf, sem, *, page_size: int, chunk: int):
+def _paged_attn_kernel(pt_ref, pos_ref, layer_ref, q_ref, pk_hbm, pv_hbm,
+                       o_ref, kbuf, vbuf, sem, *, page_size: int, chunk: int):
     b = pl.program_id(0)
+    layer = layer_ref[0]
     _, K, G, hd = q_ref.shape
     ps = page_size
     T = chunk * ps
@@ -117,9 +122,9 @@ def _paged_attn_kernel(pt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
             pid = jnp.maximum(pt_ref[b, t * chunk + j], 0)
             dst = pl.ds(j * ps, ps)
             out.append(pltpu.make_async_copy(
-                pk_hbm.at[pid], kbuf.at[slot, dst], sem.at[0, slot]))
+                pk_hbm.at[layer, pid], kbuf.at[slot, dst], sem.at[0, slot]))
             out.append(pltpu.make_async_copy(
-                pv_hbm.at[pid], vbuf.at[slot, dst], sem.at[1, slot]))
+                pv_hbm.at[layer, pid], vbuf.at[slot, dst], sem.at[1, slot]))
         return out
 
     for cp in copies(0, 0):
@@ -159,13 +164,15 @@ def _paged_attn_kernel(pt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
 
 
 def paged_attention(q: jax.Array,          # (B, H, hd) decode query
-                    pk: jax.Array,         # (P, ps, K, hd) shared pool
+                    pk: jax.Array,         # (L, P, ps, K*hd) stacked pool
                     pv: jax.Array,
                     page_table: jax.Array,  # (B, max_pages) int32,
                                             # max_pages % chunk == 0
                     pos: jax.Array,         # (B,) int32 decode positions
+                    layer: jax.Array,       # int32 scalar: the pool's layer
                     *, chunk: int = 1, interpret: bool = False) -> jax.Array:
-    """Fused paged GQA decode attention.  Returns (B, H, hd) in q.dtype.
+    """Fused paged GQA decode attention over layer ``layer`` of the
+    stacked pools.  Returns (B, H, hd) in q.dtype.
 
     ``chunk`` pages fold into the accumulator per loop step (ops.py
     pads the table so it divides ``max_pages``): the per-iteration
@@ -173,30 +180,32 @@ def paged_attention(q: jax.Array,          # (B, H, hd) decode query
     unchanged up to exact no-op pages, so any chunk size is
     bit-identical to the matching jnp walk."""
     B, H, hd = q.shape
-    P, ps, K, _ = pk.shape
+    ps, lanes = pk.shape[2], pk.shape[3]
+    K = lanes // hd
     max_pages = page_table.shape[1]
-    if H % K or max_pages % chunk:
-        raise ValueError(f"heads {H} % kv_heads {K}, table width "
-                         f"{max_pages} % chunk {chunk} must both be 0")
+    if H % K or lanes != K * hd or max_pages % chunk:
+        raise ValueError(f"heads {H} % kv_heads {K}, pool lanes {lanes} "
+                         f"% head_dim {hd}, table width {max_pages} % "
+                         f"chunk {chunk} must all be 0")
     G = H // K
     qg = scaled_query(q, K)
-    blk = pl.BlockSpec((1, K, G, hd), lambda b, pt, pos: (b, 0, 0, 0))
+    blk = pl.BlockSpec((1, K, G, hd), lambda b, pt, pos, layer: (b, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         partial(_paged_attn_kernel, page_size=ps, chunk=chunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[blk, pool, pool],
             out_specs=blk,
             scratch_shapes=[
-                pltpu.VMEM((2, chunk * ps, K * hd), pk.dtype),
-                pltpu.VMEM((2, chunk * ps, K * hd), pv.dtype),
+                pltpu.VMEM((2, chunk * ps, lanes), pk.dtype),
+                pltpu.VMEM((2, chunk * ps, lanes), pv.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
-      pk.reshape(P, ps, K * hd), pv.reshape(P, ps, K * hd))
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, pk, pv)
     return out.reshape(B, H, hd)

@@ -270,17 +270,20 @@ def attention(
     return out.reshape(B, Sq, H, hd)
 
 
-def gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Read a paged KV pool through a page table.
+def gather_pages(pool: jax.Array, page_table: jax.Array,
+                 layer) -> jax.Array:
+    """Read layer ``layer`` of a stacked paged KV pool through a page
+    table.
 
-    ``pool_leaf``: (P, page_size, K, hd) shared page pool;
-    ``page_table``: (B, max_pages) int32, each row the sequence's pages
-    in logical order (unallocated entries are -1).  Returns
-    (B, max_pages * page_size, K, hd): row ``r`` of lane ``b`` is
-    logical position ``r`` — exactly the contiguous cache layout —
-    so the existing per-sequence ``kv_valid_len`` masks apply
-    unchanged (positions ``>= pos+1`` are masked, which covers every
-    row of an unallocated page).  Unallocated/trash entries
+    ``pool``: (L, P, page_size, K*hd) stacked page pools (one gather
+    indexes the layer and the pages together, so the layer's pool is
+    never sliced out); ``page_table``: (B, max_pages) int32, each row
+    the sequence's pages in logical order (unallocated entries are
+    -1).  Returns (B, max_pages * page_size, K*hd): row ``r`` of lane
+    ``b`` is logical position ``r`` — the contiguous cache layout once
+    the caller splits the heads — so the per-sequence ``kv_valid_len``
+    masks apply unchanged (positions ``>= pos+1`` are masked, which
+    covers every row of an unallocated page).  Unallocated/trash entries
     (``page_table <= 0``) are replaced with exact zeros: the softmax
     mask gives them probability 0, but a zero probability times a NaN
     or inf value row would still be NaN in the weighted sum, so the
@@ -290,12 +293,12 @@ def gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
     ``jnp.where`` (never a multiplicative mask — ``0 * nan`` is nan)
     is bit-transparent for finite garbage.
     """
-    gathered = pool_leaf[jnp.maximum(page_table, 0)]   # (B, MP, ps, K, hd)
+    gathered = pool[layer, jnp.maximum(page_table, 0)]  # (B, MP, ps, K*hd)
     b, mp, ps = gathered.shape[:3]
     valid = (page_table > 0).reshape(
         b, mp, *([1] * (gathered.ndim - 2)))
     gathered = jnp.where(valid, gathered, jnp.zeros((), gathered.dtype))
-    return gathered.reshape(b, mp * ps, *pool_leaf.shape[2:])
+    return gathered.reshape(b, mp * ps, *pool.shape[3:])
 
 
 def windowed_prefill_attention(

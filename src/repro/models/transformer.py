@@ -221,15 +221,21 @@ def _attn_decode(x, ap, cfg: ModelConfig, cache, pos, kv_kbits=None):
     return out, {"k": ck, "v": cv}
 
 
-def _attn_decode_paged(x, ap, cfg: ModelConfig, pc, page_table, pos,
-                       kv_kbits=None, write_mask=None, paged_kernel=False):
-    """One-token attention against a *paged* KV pool.  x: (B, 1, D).
+def _attn_decode_paged(x, ap, cfg: ModelConfig, pk, pv, layer, page_table,
+                       pos, kv_kbits=None, write_mask=None,
+                       paged_kernel=False):
+    """One-token attention of layer ``layer`` against the *paged* KV
+    pools.  x: (B, 1, D).
 
-    ``pc`` holds the layer's shared pools ``{"k","v"}: (P, ps, K, hd)``;
-    ``page_table`` (B, max_pages) maps each lane's logical pages into
-    the pool (see serve/paging.py).  ``pos`` is always a (B,) vector —
-    the paged engine is ragged by construction.  The write lands at
-    ``pool[page_table[b, pos//ps], pos % ps]``; lanes outside
+    ``pk``/``pv`` are the whole stacked pools ``(L, P, ps, K*hd)``
+    (``paged_pool_specs``), carried through the layer loop and updated
+    in place: the B new rows are one scatter at ``[layer, pidx, off]``,
+    and the read takes layer ``layer`` straight from the stack, so no
+    layer's pool is sliced out or copied.  ``page_table`` (B,
+    max_pages) maps each lane's logical pages into the pool (see
+    serve/paging.py).  ``pos`` is always a (B,) vector — the paged
+    engine is ragged by construction.  The write lands at
+    ``pool[layer, page_table[b, pos//ps], pos % ps]``; lanes outside
     ``write_mask`` (dead lanes waiting for admission) AND lanes whose
     position has outrun their page table (``pos // ps >= max_pages`` —
     an engine bug, but it must fail safe) are routed to the reserved
@@ -238,13 +244,14 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pc, page_table, pos,
     (``gather_pages``, the oracle) and masks with the same per-sequence
     ``kv_valid_len`` as the contiguous path, or — with
     ``paged_kernel=True`` — walks the page table in place through the
-    fused kernel (kernels/paged_attn), which never materializes the
-    gathered cache; both keep paged decode token-identical to the
-    contiguous engine (locked by tests/test_serve_paged.py).
-    ``kv_kbits`` fake-quantizes the written slot at the same slot
-    granularity as the contiguous path (one scale per (K, hd) row —
-    the byte *accounting* is per page, the numerics per slot, so
-    parity survives FRAC).
+    fused kernel (kernels/paged_attn), which DMAs the layer's pages
+    from the stack and never materializes the gathered cache; both
+    keep paged decode token-identical to the contiguous engine (locked
+    by tests/test_serve_paged.py).  ``kv_kbits`` fake-quantizes the
+    written slot at the same slot granularity as the contiguous path
+    (one scale per (K, hd) row — the byte *accounting* is per page,
+    the numerics per slot, so parity survives FRAC).  Returns
+    (out, pk, pv).
     """
     q = jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, ap["wk"])
@@ -259,7 +266,7 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pc, page_table, pos,
 
             k = fops.fake_quant_slots(k, kv_kbits, row_dims=2)
             v = fops.fake_quant_slots(v, kv_kbits, row_dims=2)
-        ps = pc["k"].shape[1]
+        ps = pk.shape[2]
         b = x.shape[0]
         mp = page_table.shape[1]
         cols_raw = pos // ps
@@ -273,23 +280,24 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pc, page_table, pos,
             ok = ok & write_mask
         pidx = jnp.where(ok, pidx, 0)                      # trash page
         off = pos % ps
-        pk = pc["k"].at[pidx, off].set(k[:, 0])
-        pv = pc["v"].at[pidx, off].set(v[:, 0])
+        pk = pk.at[layer, pidx, off].set(k[:, 0].reshape(b, -1))
+        pv = pv.at[layer, pidx, off].set(v[:, 0].reshape(b, -1))
     with jax.named_scope(spans.ATTN_READ):
         if paged_kernel:
             from repro.kernels.paged_attn import ops as pops
 
             out = pops.paged_attention(q[:, 0], pk, pv, page_table,
-                                       pos)[:, None]
+                                       pos, layer)[:, None]
         else:
-            kb = gather_pages(pk, page_table)
-            vb = gather_pages(pv, page_table)
+            kv_shape = (b, -1, *k.shape[2:])               # (B, S, K, hd)
+            kb = gather_pages(pk, page_table, layer).reshape(kv_shape)
+            vb = gather_pages(pv, page_table, layer).reshape(kv_shape)
             out = attention(
                 q, kb, vb, causal=False, kv_valid_len=pos + 1,
                 q_positions=ppos
             )
     out = jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
-    return out, {"k": pk, "v": pv}
+    return out, pk, pv
 
 
 def _mlp(x, mp, cfg: ModelConfig):
@@ -397,21 +405,21 @@ def block_decode(x, bp, bc, cfg: ModelConfig, pos, kv_kbits=None):
     return x, new_cache
 
 
-def block_decode_paged(x, bp, pc, cfg: ModelConfig, page_table, pos,
-                       kv_kbits=None, write_mask=None, paged_kernel=False):
-    """One token through one period block against paged pools.
+def block_decode_paged(x, bp, pool, layer, cfg: ModelConfig, page_table,
+                       pos, kv_kbits=None, write_mask=None,
+                       paged_kernel=False):
+    """One token through period block ``layer`` against the stacked
+    paged pools, which come back with this block's rows written.
     Only pure-attention blocks page (model.supports_paged)."""
-    new_pc: dict[str, Any] = {}
+    pool = dict(pool)
     for j, (mixer, mlp_kind) in enumerate(sublayer_kinds(cfg)):
         assert mixer == "attn", "paged decode is attention-only"
         with jax.named_scope(spans.ATTN):
             h = rms_norm(x, bp[f"norm1_{j}"])
-            mixed, c = _attn_decode_paged(
-                h, bp[f"attn_{j}"], cfg,
-                {"k": pc[f"k_{j}"], "v": pc[f"v_{j}"]},
-                page_table, pos, kv_kbits, write_mask, paged_kernel,
+            mixed, pool[f"k_{j}"], pool[f"v_{j}"] = _attn_decode_paged(
+                h, bp[f"attn_{j}"], cfg, pool[f"k_{j}"], pool[f"v_{j}"],
+                layer, page_table, pos, kv_kbits, write_mask, paged_kernel,
             )
-        new_pc[f"k_{j}"], new_pc[f"v_{j}"] = c["k"], c["v"]
         with jax.named_scope(spans.MLP):
             if cfg.parallel_block:
                 x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg,
@@ -420,7 +428,7 @@ def block_decode_paged(x, bp, pc, cfg: ModelConfig, page_table, pos,
                 x = x + mixed
                 h2 = rms_norm(x, bp[f"norm2_{j}"])
                 x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)
-    return x, new_pc
+    return x, pool
 
 
 # ---------------------------------------------------------------------------
@@ -533,27 +541,42 @@ def decode_step_paged(cfg: ModelConfig, params, pool, page_table, tokens,
                       pos, kv_kbits=None, write_mask=None,
                       paged_kernel=False):
     """tokens: (B,) int32; pos: (B,) int32 per-sequence positions;
-    ``pool``: per-layer paged KV pools (stacked over period blocks like
-    the contiguous cache, leaves (n_periods, P, ps, K, hd));
+    ``pool``: the paged KV pools, stacked over period blocks (leaves
+    ``(n_periods, P, ps, K*hd)``, ``paged_pool_specs``);
     ``page_table``: (B, max_pages), one table for every layer (the
-    whole stack grows in lockstep).  ``paged_kernel`` reads through the
-    fused page-walk kernel instead of the gather oracle (see
-    kernels/paged_attn).  Returns (logits, pool)."""
+    whole stack grows in lockstep).  The pool is the layer scan's
+    *carry*, next to the activations and the layer index, while the
+    weights are its scanned operand: each layer writes its B new rows
+    into the carried stack and reads its pages from it in place, so a
+    step never slices, copies or rebuilds the pool.  ``paged_kernel``
+    reads through the fused page-walk kernel instead of the gather
+    oracle (see kernels/paged_attn).  Returns (logits, pool)."""
     x = params["embed"][tokens][:, None, :]                 # (B, 1, D)
 
-    def body(x, bp_pc):
-        bp, pc = bp_pc
-        return block_decode_paged(x, bp, pc, cfg, page_table, pos,
-                                  kv_kbits, write_mask, paged_kernel)
+    def body(carry, bp):
+        x, pool, layer = carry
+        x, pool = block_decode_paged(x, bp, pool, layer, cfg, page_table,
+                                     pos, kv_kbits, write_mask,
+                                     paged_kernel)
+        return (x, pool, layer + 1), None
 
-    x, new_pool = lax.scan(body, x, (params["layers"], pool))
+    (x, pool, _), _ = lax.scan(
+        body, (x, pool, jnp.asarray(0, jnp.int32)), params["layers"])
     with jax.named_scope(spans.HEAD):
         x = rms_norm(x, params["final_norm"])
-        return _lm_head(cfg, params, x)[:, 0], new_pool
+        return _lm_head(cfg, params, x)[:, 0], pool
 
 
 def paged_pool_specs(cfg: ModelConfig, n_pages: int, page_size: int) -> dict:
-    """LeafSpecs for the shared page pool (paged serve engine)."""
+    """LeafSpecs for the shared page pool (paged serve engine): one
+    ``(n_periods, n_pages, page_size, K*hd)`` leaf per k/v sublayer.
+    The last axis holds a slot's KV heads side by side, the paged
+    kernel's own lane-dense layout (kernels/paged_attn), so the kernel
+    DMAs pages straight from the stored pool; the prefill fill and the
+    gather read reshape only their own (B, S, K, hd) operands.  The
+    axis keeps the ``kv_heads`` label: the sharding rule splits it
+    into contiguous column blocks, whole heads when K divides the
+    model axis."""
     n_periods = cfg.num_layers // block_period(cfg)
     K, hd = cfg.num_kv_heads, cfg.head_dim
     block: dict[str, LeafSpec] = {}
@@ -561,8 +584,8 @@ def paged_pool_specs(cfg: ModelConfig, n_pages: int, page_size: int) -> dict:
         assert mixer == "attn", "paged pools are attention-only"
         for name in ("k", "v"):
             block[f"{name}_{j}"] = LeafSpec(
-                (n_pages, page_size, K, hd),
-                ("pages", "page_slots", "kv_heads", "head_dim"),
+                (n_pages, page_size, K * hd),
+                ("pages", "page_slots", "kv_heads"),
                 init="zeros",
             )
     return jax.tree.map(

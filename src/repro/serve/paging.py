@@ -2,11 +2,12 @@
 
 The serve engine's paged layout (serve/engine.py) replaces the
 contiguous per-lane cache ``(B, bucket_max + horizon, K, hd)`` with a
-shared **page pool** ``(P, page_size, K, hd)`` per layer plus one
+shared **page pool** ``(L, P, page_size, K*hd)`` stacked over layers
+(a slot's KV heads side by side, the paged kernel's layout) plus one
 **page table** ``(B, max_pages)`` shared by every layer (all layers
 grow in lockstep, so one allocation covers the whole stack).  Logical
 position ``p`` of lane ``b`` lives at
-``pool[page_table[b, p // page_size], p % page_size]``.
+``pool[layer, page_table[b, p // page_size], p % page_size]``.
 
 Conventions (shared by the jitted decode loop and the property tests):
 
@@ -178,8 +179,9 @@ def pool_scatter_indices(full_table: np.ndarray, lens, seq_len: int,
 
 def fill_pool(pool_leaf, prefill_leaf, page_idx, slot_idx):
     """Scatter a prefill cache leaf ``(L, R, S, K, hd)`` into a pool
-    leaf ``(L, P, page_size, K, hd)`` at the precomputed flat targets
-    (see :func:`pool_scatter_indices`)."""
+    leaf ``(L, P, page_size, K*hd)`` at the precomputed flat targets
+    (see :func:`pool_scatter_indices`); each row's heads are merged
+    into the pool's lane-dense slot."""
     l = prefill_leaf.shape[0]
-    vals = prefill_leaf.reshape(l, -1, *prefill_leaf.shape[3:])
+    vals = prefill_leaf.reshape(l, -1, pool_leaf.shape[-1])
     return pool_leaf.at[:, page_idx, slot_idx].set(vals, mode="drop")

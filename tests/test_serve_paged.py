@@ -17,6 +17,8 @@ Three layers of lock:
    host syncs and strictly less peak resident KV than the contiguous
    bucket-max layout.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -174,11 +176,11 @@ def test_pool_scatter_routes_pad_rows_to_nowhere():
     assert pi[0, 6:].tolist() == [4, 4]               # pad rows dropped
     assert pi[1, :2].tolist() == [3, 3] and (pi[1, 2:] == 4).all()
     assert oi[0].tolist() == [0, 1, 2, 3, 0, 1, 2, 3]
-    pool = jnp.zeros((1, 4, 4, 1, 1), jnp.float32)
+    pool = jnp.zeros((1, 4, 4, 1), jnp.float32)
     leaf = jnp.arange(16, dtype=jnp.float32).reshape(1, 2, 8, 1, 1)
     filled = paging.fill_pool(pool, leaf, jnp.asarray(pi.reshape(-1)),
                               jnp.asarray(oi.reshape(-1)))
-    got = np.asarray(filled)[0, :, :, 0, 0]
+    got = np.asarray(filled)[0, :, :, 0]
     assert got[1].tolist() == [0, 1, 2, 3]            # lane 0 page 0
     assert got[2].tolist() == [4, 5, 0, 0]            # lane 0 page 1 head
     assert got[3].tolist() == [8, 9, 0, 0]            # lane 1 page 0 head
@@ -188,11 +190,13 @@ def test_pool_scatter_routes_pad_rows_to_nowhere():
 def test_gather_pages_restores_logical_order():
     from repro.models.common import gather_pages
 
-    pool = jnp.arange(4 * 2 * 1 * 1, dtype=jnp.float32).reshape(4, 2, 1, 1)
+    pool = jnp.arange(2 * 4 * 2, dtype=jnp.float32).reshape(2, 4, 2, 1)
     table = jnp.asarray([[3, 1], [2, -1]], jnp.int32)
-    got = np.asarray(gather_pages(pool, table))[:, :, 0, 0]
+    got = np.asarray(gather_pages(pool, table, 0))[:, :, 0]
     assert got[0].tolist() == [6.0, 7.0, 2.0, 3.0]
     assert got[1, :2].tolist() == [4.0, 5.0]          # tail rows are masked
+    got = np.asarray(gather_pages(pool, table, 1))[:, :, 0]
+    assert got[0].tolist() == [14.0, 15.0, 10.0, 11.0]  # the second layer
 
 
 # ---------------------------------------------------------------------------
@@ -403,8 +407,9 @@ def test_paged_solo_degenerates_to_single_lane():
 # ---------------------------------------------------------------------------
 
 
-def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32):
-    """Random pool + valid page tables + ragged positions: every lane's
+def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32, L=3):
+    """Random stacked pools ``(L, P, ps, K*hd)`` (every layer its own
+    contents) + valid page tables + ragged positions: every lane's
     allocated prefix covers its own ``pos`` (the invariant the engine's
     allocator maintains), page ids distinct across lanes, -1 tails."""
     rng = np.random.default_rng(seed)
@@ -412,8 +417,8 @@ def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32):
     H, K, hd = 4, 2, 8
     P = B * mp + 1                               # + trash page 0
     q = jnp.asarray(rng.standard_normal((B, H, hd)), dtype)
-    pk = jnp.asarray(rng.standard_normal((P, ps, K, hd)), dtype)
-    pv = jnp.asarray(rng.standard_normal((P, ps, K, hd)), dtype)
+    pk = jnp.asarray(rng.standard_normal((L, P, ps, K * hd)), dtype)
+    pv = jnp.asarray(rng.standard_normal((L, P, ps, K * hd)), dtype)
     ids = rng.permutation(np.arange(1, P))
     table = np.full((B, mp), -1, np.int32)
     pos = np.zeros((B,), np.int32)
@@ -426,11 +431,17 @@ def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32):
     return q, pk, pv, jnp.asarray(table), jnp.asarray(pos)
 
 
-def _oracle_attn(q, pk, pv, table, pos):
+def _oracle_attn(q, pk, pv, table, pos, layer):
+    """Gather + common.attention over layer ``layer``, sliced out of
+    the stack on the host so the oracle shares no layer indexing with
+    the paths under test."""
     from repro.models.common import attention, gather_pages
 
-    kb, vb = gather_pages(pk, table), gather_pages(pv, table)
-    return attention(q[:, None], kb, vb, causal=False,
+    B, H, hd = q.shape
+    kv = [gather_pages(jnp.asarray(np.asarray(p)[layer:layer + 1]), table,
+                       0).reshape(B, -1, p.shape[-1] // hd, hd)
+          for p in (pk, pv)]
+    return attention(q[:, None], *kv, causal=False,
                      kv_valid_len=pos + 1, q_positions=pos[:, None])[:, 0]
 
 
@@ -442,31 +453,52 @@ def test_paged_attn_modes_agree_and_match_oracle():
     from repro.kernels.paged_attn import ops as pops
 
     q, pk, pv, table, pos = _rand_paged_fixture(0, B=3, ps=4)
-    o_jnp = pops.paged_attention(q, pk, pv, table, pos, mode="jnp")
-    o_int = pops.paged_attention(q, pk, pv, table, pos,
+    o_jnp = pops.paged_attention(q, pk, pv, table, pos, 1, mode="jnp")
+    o_int = pops.paged_attention(q, pk, pv, table, pos, 1,
                                  mode="pallas_interpret")
     assert jnp.array_equal(o_jnp, o_int)
-    oracle = _oracle_attn(q, pk, pv, table, pos)
+    oracle = _oracle_attn(q, pk, pv, table, pos, 1)
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(oracle),
                                rtol=1e-5, atol=1e-6)
     with pytest.raises(ValueError, match="expected one of"):
-        pops.paged_attention(q, pk, pv, table, pos, mode="cuda")
+        pops.paged_attention(q, pk, pv, table, pos, 1, mode="cuda")
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas_interpret"])
+def test_paged_attn_reads_the_given_layer(mode):
+    """The read takes layer ``l`` of the stacked pool in place: against
+    the oracle on each of three layers with different contents, and a
+    read of any other layer is far off (so a wrong-layer DMA or gather
+    fails here)."""
+    from repro.kernels.paged_attn import ops as pops
+
+    q, pk, pv, table, pos = _rand_paged_fixture(5, B=3, ps=4, L=3)
+    oracles = [np.asarray(_oracle_attn(q, pk, pv, table, pos, l))
+               for l in range(3)]
+    read = jax.jit(lambda l: pops.paged_attention(q, pk, pv, table, pos, l,
+                                                  mode=mode))
+    for l in range(3):
+        got = np.asarray(read(jnp.int32(l)))
+        np.testing.assert_allclose(got, oracles[l], rtol=1e-5, atol=1e-6)
+        for other in set(range(3)) - {l}:
+            assert np.abs(got - oracles[other]).max() > 0.1
 
 
 @settings(max_examples=12, deadline=None)
 @given(st.integers(0, 10**6), st.sampled_from([2, 4]),
-       st.integers(1, 3))
-def test_paged_attn_property_vs_oracle(seed, ps, B):
-    """Property lock over random valid page tables / ragged positions:
-    fused walk == gather oracle (rounding), jnp == interpret (bits)."""
+       st.integers(1, 3), st.integers(0, 2))
+def test_paged_attn_property_vs_oracle(seed, ps, B, layer):
+    """Property lock over random valid page tables / ragged positions
+    and layers: fused walk == gather oracle (rounding), jnp ==
+    interpret (bits)."""
     from repro.kernels.paged_attn import ops as pops
 
     q, pk, pv, table, pos = _rand_paged_fixture(seed, B=B, ps=ps)
-    o_jnp = pops.paged_attention(q, pk, pv, table, pos, mode="jnp")
-    o_int = pops.paged_attention(q, pk, pv, table, pos,
+    o_jnp = pops.paged_attention(q, pk, pv, table, pos, layer, mode="jnp")
+    o_int = pops.paged_attention(q, pk, pv, table, pos, layer,
                                  mode="pallas_interpret")
     assert jnp.array_equal(o_jnp, o_int)
-    oracle = _oracle_attn(q, pk, pv, table, pos)
+    oracle = _oracle_attn(q, pk, pv, table, pos, layer)
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(oracle),
                                rtol=1e-5, atol=1e-6)
 
@@ -481,16 +513,116 @@ def test_trash_page_poison_is_masked(ps):
     from repro.kernels.paged_attn import ops as pops
 
     q, pk, pv, table, pos = _rand_paged_fixture(7, B=3, ps=ps)
-    clean = {m: pops.paged_attention(q, pk, pv, table, pos, mode=m)
+    clean = {m: pops.paged_attention(q, pk, pv, table, pos, 2, mode=m)
              for m in ("jnp", "pallas_interpret")}
-    clean_g = _oracle_attn(q, pk, pv, table, pos)
-    pk_p = pk.at[0].set(jnp.nan)
-    pv_p = pv.at[0].set(jnp.inf)
+    clean_g = _oracle_attn(q, pk, pv, table, pos, 2)
+    pk_p = pk.at[:, 0].set(jnp.nan)
+    pv_p = pv.at[:, 0].set(jnp.inf)
     for m, ref in clean.items():
-        got = pops.paged_attention(q, pk_p, pv_p, table, pos, mode=m)
+        got = pops.paged_attention(q, pk_p, pv_p, table, pos, 2, mode=m)
         assert jnp.array_equal(got, ref), f"poison leaked through {m}"
-    got_g = _oracle_attn(q, pk_p, pv_p, table, pos)
+    got_g = _oracle_attn(q, pk_p, pv_p, table, pos, 2)
     assert jnp.array_equal(got_g, clean_g), "poison leaked through gather"
+
+
+# producers allowed to yield a pool-sized array inside the decode loop:
+# the loop's parameters and carry, and the in-place row scatter
+_POOL_PRODUCERS = {"parameter", "get-tuple-element", "bitcast", "scatter"}
+
+
+def pool_movers(hlo_text: str, pool_elems: set) -> list:
+    """Instructions of an optimized HLO module that produce an array
+    with as many elements as the stacked pool or one layer's pool
+    (whatever its shape or layout) from anything but the loop's
+    parameters, its carry or the row scatter — a fusion counts by the
+    opcode of its root.  Each as (name, producer, shape)."""
+    inst = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                      r"([\w-]+)\((.*)")
+    roots, comp, found = {}, None, []
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head and "=" not in line.split("(")[0]:
+            comp = head.group(1)
+        m = inst.match(line)
+        if m and line.lstrip().startswith("ROOT"):
+            roots[comp] = m.group(3)
+        if m:
+            found.append(m.groups())
+    movers = []
+    for name, shape, op, rest in found:
+        if int(np.prod([int(d) for d in shape.split(",") if d])) \
+                not in pool_elems:
+            continue
+        if op == "fusion":
+            op = roots.get(re.search(r"calls=%(\S+?),?\s", rest + " ")
+                           .group(1), op)
+        if op not in _POOL_PRODUCERS:
+            movers.append((name, op, shape))
+    return movers
+
+
+def _layer_scans(jaxpr, weight_shape):
+    """Every ``scan`` in a jaxpr, nested ones included, that scans an
+    operand of ``weight_shape`` (a stacked layer weight)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            xs = eqn.invars[eqn.params["num_consts"]
+                            + eqn.params["num_carry"]:]
+            if weight_shape in [v.aval.shape for v in xs]:
+                out.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _layer_scans(sub, weight_shape)
+    return out
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_paged_loop_carries_the_pool_and_never_copies_it(kernel):
+    """The layer scan of the paged decode loop carries the stacked pool
+    (weights are its only scanned operand), and the compiled loop moves
+    no pool: no op outside the loop's parameters, its carry and the
+    row scatter yields an array the size of the stack or of one
+    layer's pool — no copy, no dynamic-slice or dynamic-update-slice,
+    no relayout.  Weights and pool are float32 here: the CPU backend
+    widens a bf16 scatter to float32 around its whole operand, a
+    CPU-only rewrite (tests/test_tpu_compile.py checks the bf16 loop
+    for the chip)."""
+    cfg = get_tiny(ARCH)
+    B, Q, mp, P, ps = 2, 2, 4, 23, 4       # odd P: pool sizes are unique
+    L = cfg.num_layers
+    lanes = cfg.num_kv_heads * cfg.head_dim
+    from repro.models.common import is_leaf_spec
+    from repro.serve.engine import build_paged_decode_loop
+
+    pool = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
+        model.paged_pool_specs(cfg, P, ps), is_leaf=is_leaf_spec)
+    assert {a.shape for a in jax.tree.leaves(pool)} == {(L, P, ps, lanes)}
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    loop = build_paged_decode_loop(cfg, kv_kbits=8, out_cap=8, page_size=ps,
+                                   paged_kernel=kernel)
+    f32 = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32),
+                       model.abstract_params(cfg))
+    args = (f32, pool, i32(B, mp), i32(P), i32(),
+            i32(B), i32(B), i32(Q), i32(Q), i32(Q, mp), i32(B + Q))
+    wq = f32["layers"]["attn_0"]["wq"].shape
+    (scan,) = _layer_scans(jax.make_jaxpr(loop)(*args).jaxpr, wq)
+    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+    shape = lambda vs: [v.aval.shape for v in vs]          # noqa: E731
+    pool_shape = (L, P, ps, lanes)
+    assert shape(scan.invars[nc:nc + nk]).count(pool_shape) == 2
+    assert pool_shape not in shape(scan.invars[:nc] + scan.invars[nc + nk:])
+    assert shape(scan.outvars[:nk]).count(pool_shape) == 2
+    assert pool_shape not in shape(scan.outvars[nk:])
+    hlo = loop.lower(*args).compile().as_text()
+    assert "scatter" in hlo
+    assert pool_movers(hlo, {L * P * ps * lanes, P * ps * lanes}) == []
 
 
 def test_paged_write_overflow_routes_to_trash():

@@ -66,18 +66,59 @@ def _compile(fn, one_chip, *shapes):
 def test_paged_attention_llama3_2_3b(one_chip):
     cfg = get_config("llama3.2-3b")
     B, P, ps, max_pages = 8, 512, 16, 128          # 2048 slots per lane
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    H, K, hd, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, 4
     compiled = _compile(
-        lambda q, pk, pv, pt, pos: paged_ops.paged_attention(
-            q, pk, pv, pt, pos, mode="pallas"),
+        lambda q, pk, pv, pt, pos, layer: paged_ops.paged_attention(
+            q, pk, pv, pt, pos, layer, mode="pallas"),
         one_chip,
-        ((B, H, hd), jnp.bfloat16), ((P, ps, K, hd), jnp.bfloat16),
-        ((P, ps, K, hd), jnp.bfloat16), ((B, max_pages), jnp.int32),
-        ((B,), jnp.int32))
-    # the pool stays in HBM: the program's scratch holds a few chunks of
-    # pages, never the (P, ps, K, hd) pool itself
+        ((B, H, hd), jnp.bfloat16), ((L, P, ps, K * hd), jnp.bfloat16),
+        ((L, P, ps, K * hd), jnp.bfloat16), ((B, max_pages), jnp.int32),
+        ((B,), jnp.int32), ((), jnp.int32))
+    # the stacked pool stays in HBM: the program's scratch holds a few
+    # chunks of pages, never one layer's (P, ps, K*hd) pool
     pool_bytes = P * ps * K * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def test_paged_decode_loop_stablelm_12b_moves_no_pool(one_chip,
+                                                      monkeypatch):
+    """The benchmark's stablelm-12b stage (10 layers at published
+    widths, hd 160) through the paged decode loop with the Pallas
+    kernel, 8 lanes and 8 staged: the chip's program updates the bf16
+    pool in place.  No op but the loop's parameters, its carry and the
+    row scatter yields an array the size of the stack or of one
+    layer's pool, and the temp stays under one layer's pool."""
+    from test_serve_paged import pool_movers
+
+    from repro.models import model
+    from repro.models.common import is_leaf_spec
+    from repro.serve.engine import build_paged_decode_loop
+
+    # off a TPU the auto mode is the jnp walk; the chip runs the kernel
+    monkeypatch.setenv(paged_ops.ENV_VAR, "pallas")
+    cfg = get_config("stablelm-12b").replace(num_layers=10)
+    B, Q, mp, P, ps = 8, 8, 64, 1000, 16
+    lanes = cfg.num_kv_heads * cfg.head_dim
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                        model.paged_pool_specs(cfg, P, ps),
+                        is_leaf=is_leaf_spec)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params(cfg))
+    i32 = lambda *shape: sds(shape, jnp.int32)          # noqa: E731
+    loop = build_paged_decode_loop(cfg, kv_kbits=8, out_cap=512,
+                                   page_size=ps, paged_kernel=True)
+    compiled = loop.lower(params, pool, i32(B, mp), i32(P), i32(), i32(B),
+                          i32(B), i32(Q), i32(Q), i32(Q, mp),
+                          i32(B + Q)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer = P * ps * lanes                     # one layer's pool, elements
+    assert pool_movers(text, {cfg.num_layers * layer, layer}) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer  # bf16
 
 
 @pytest.mark.parametrize("k", [8, 11])
