@@ -8,12 +8,15 @@ For each seed, in one process: weights made from the seed, the cell's
 engine (built once; its weights swapped per seed), one group of the
 cell's deck served through ``submit`` + ``run`` as in a run's window,
 and the same sample a run compares (the longest request and others
-drawn from the seed).  Prints one JSON line per seed: the widest gap of
-a served token below the reference's best (the program's reading) and,
-for the control seeds, the widest gap of the token the control ranks
-first (``bench/reference.py``), each with the sum of the gaps and the
-number of tokens off the reference's first choice.  Benchmark runs
-never run the control.
+drawn from the seed, as many as the configuration's ``check`` block
+says).  Prints one JSON line per seed: the widest gap of a served token
+below the reference's best (the program's reading) and, for the
+control seeds, the widest gap of the token the control ranks first
+(the reference the configuration names, ``bench/references/
+<name>.py``), each with the sum of the gaps and the number of tokens
+off the reference's first choice, and the seconds the reference took
+(``reference_s``, the control's pass included on a control seed).
+Benchmark runs never run the control.
 """
 from __future__ import annotations
 
@@ -21,6 +24,7 @@ import argparse
 import json
 import os
 import sys
+import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -31,7 +35,7 @@ def readings(workload: str, seeds, control_seeds, rehearse: bool = False,
     """Yield {"seed", "program", "control"?, "tokens"} per seed."""
     import numpy as np
 
-    from bench import loadgen, reference, run, spec, weights
+    from bench import loadgen, run, spec, weights
     from repro.models import model
     from repro.serve.engine import ServeEngine
 
@@ -45,9 +49,11 @@ def readings(workload: str, seeds, control_seeds, rehearse: bool = False,
     kw = dict(cell.config["engine"])
     group = kw["max_batch"] + kw["stage_depth"]
     div = run.REHEARSE_DIV if rehearse else 1
-    eng = None
+    eng = params = None
     for seed in seeds:
-        params = weights.make_params(m, seed)
+        if eng is not None:      # one copy of the weights on the device at a time
+            eng.params = params = None
+        params = weights.make_params(cell.reference.layout(m), seed)
         weights.check_layout(params, model.abstract_params(mcfg))
         if eng is None:
             eng = ServeEngine(mcfg, params, **kw)
@@ -55,16 +61,18 @@ def readings(workload: str, seeds, control_seeds, rehearse: bool = False,
         reqs = loadgen.make_group(cell.traffic, 0, group, seed,
                                   m["vocab_size"], None, div)
         served = run.serve(eng, reqs, 0, [0.0] * len(reqs))
-        chosen = run.sample(served, cell.traffic["check_sample"], seed)
+        chosen = run.sample(served, cell.config["check"]["sample"], seed)
         ctrl = seed in control_seeds
         gaps = {"program": [], "control": []}
+        t0 = time.perf_counter()
         for s in chosen:
-            g = reference.gaps(params, m, s.prompt, s.tokens,
-                               kw["kv_frac_kbits"], control=ctrl)
+            g = cell.reference.gaps(params, m, s.prompt, s.tokens,
+                                    kw["kv_frac_kbits"], control=ctrl)
             for k, v in g.items():
                 gaps[k].append(v)
         out = {"seed": seed, "device": devs[0].device_kind,
-               "tokens": sum(len(s.tokens) for s in chosen)}
+               "tokens": sum(len(s.tokens) for s in chosen),
+               "reference_s": time.perf_counter() - t0}
         for k, v in gaps.items():
             if v:
                 v = np.concatenate(v)

@@ -46,14 +46,25 @@ class EngineTrace:
     loop_scopes: dict[str, float] = field(default_factory=dict)  # per device
     busy_iv: list[list[tuple[int, int]]] = field(default_factory=list)
 
+    def span_s(self, span: str) -> float | None:
+        """Seconds the ``span`` events cover; None without such a span."""
+        length = sum(e - s for s, e in union(self.spans.get(span, [])))
+        return length * 1e-9 if length > 0 else None
+
+    def busy_in_s(self, span: str) -> float | None:
+        """Seconds inside the ``span`` events in which the device ran an
+        op (averaged over devices); None without such a span or device."""
+        marks = union(self.spans.get(span, []))
+        if not marks or not self.busy_iv:
+            return None
+        return sum(overlap(iv, marks) for iv in self.busy_iv) * 1e-9 / len(self.busy_iv)
+
     def idle_share_in(self, span: str) -> float | None:
         """% of the ``span`` events' length in which the device ran no
         op (averaged over devices); None without such a span."""
-        marks = union(self.spans.get(span, []))
-        length = sum(e - s for s, e in marks)
-        if length <= 0 or not self.busy_iv:
+        length, busy = self.span_s(span), self.busy_in_s(span)
+        if length is None or busy is None:
             return None
-        busy = sum(overlap(iv, marks) for iv in self.busy_iv) / len(self.busy_iv)
         return 100.0 * (1.0 - busy / length)
 
     def span_gaps(self, top: int = 10) -> list[tuple[str, float]]:

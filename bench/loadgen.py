@@ -25,7 +25,6 @@ Mix keys:
   prompt     {"dist": "lognormal", "median", "sigma", "min", "max"}
              or {"dist": "uniform", "min", "max"}; tokens
   output     the same, for the number of tokens generated
-  check_sample  requests the correctness check compares
 """
 from __future__ import annotations
 

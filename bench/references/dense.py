@@ -1,4 +1,9 @@
-"""Plain float32 reference of a dense configuration, and its control.
+"""Plain float32 reference of a dense configuration, its control, and
+the weight layout it reads.
+
+A configuration names its reference (``"reference": "dense"``); the
+harness loads ``bench/references/<name>.py`` and calls its ``layout``
+(the weight tree ``bench/weights.py`` draws) and ``gaps`` (the check).
 
 Written from the configuration alone (``m``, a config's ``model``
 block) in straightforward ``jax.numpy``; it imports nothing of the
@@ -37,6 +42,42 @@ F32 = jnp.float32
 QBLOCK = 256          # query rows per attention block
 PAD = 512             # sequences are padded to a multiple of this
 VCHUNK = 16384        # vocabulary columns per head block
+
+
+def layout(m: dict) -> dict:
+    """{path: (shape, std)} of a dense decoder, as the engine serves a
+    dense stack (period-one blocks stacked over layers); std None for a
+    norm scale.  Each matrix N(0, 1/fan_in), the two that write into the
+    residual stream (``wo``, ``w_down``) a further 1/sqrt(2L) down, the
+    embedding N(0, 1).
+
+    The scale matters to the check.  With every matrix at one small std
+    the stack's branches swamp the embedding, and a squared-ReLU MLP
+    (whose outputs have a positive mean) maps every token to nearly the
+    same vector: greedy decoding then emits one token whatever the
+    prompt, so neither a fault in the KV cache nor a lower precision
+    shows.  Here the token and its context keep a say in every logit."""
+    L, D, H, K = m["num_layers"], m["d_model"], m["num_heads"], m["num_kv_heads"]
+    hd, F, V = m["head_dim"], m["d_ff"], m["vocab_size"]
+    into_residual = (2 * L) ** -0.5
+    out = {
+        "embed": ((V, D), 1.0),
+        "layers/attn_0/wq": ((L, D, H, hd), D ** -0.5),
+        "layers/attn_0/wk": ((L, D, K, hd), D ** -0.5),
+        "layers/attn_0/wv": ((L, D, K, hd), D ** -0.5),
+        "layers/attn_0/wo": ((L, H, hd, D), (H * hd) ** -0.5 * into_residual),
+        "layers/norm1_0": ((L, D), None),
+        "layers/mlp_0/w_up": ((L, D, F), D ** -0.5),
+        "layers/mlp_0/w_down": ((L, F, D), F ** -0.5 * into_residual),
+        "final_norm": ((D,), None),
+    }
+    if m["gated_mlp"]:
+        out["layers/mlp_0/w_gate"] = ((L, D, F), D ** -0.5)
+    if not m["parallel_block"]:
+        out["layers/norm2_0"] = ((L, D), None)
+    if not m["tie_embeddings"]:
+        out["lm_head"] = ((D, V), D ** -0.5)
+    return out
 
 
 def _mm(a, b):

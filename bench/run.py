@@ -18,14 +18,16 @@ Every run makes at least two ``run`` calls.  With ``--trace 1`` the
 second is profiled (with any wait for arrivals before it) and the
 per-layer metrics are read from that trace; with ``--trace 0`` the
 end-to-end metrics are printed.  After the window, the engine is freed
-and a seeded sample of the served requests, the longest among them, is
-compared with the float32 reference (``bench/reference.py``); the
-numbers compared are printed beside their limits on stderr and under
-``checks``, the last key of the result, which is the last line of
-stdout.  ``--control`` compares the reference's own choices one
-precision step down (fp8 weights, a 4-bit KV tier) in place of the
-served tokens: the check must then read false.  Benchmark runs never
-take it.
+and a seeded sample of the served requests (as many as the
+configuration's ``check`` block says), the longest among them, is
+compared with the float32 reference the configuration names
+(``bench/references/<name>.py``, which also gives the weights'
+layout); the numbers compared are printed beside their limits on
+stderr and under ``checks``, the last key of the result, which is the
+last line of stdout.  ``--control`` compares the reference's own
+choices one precision step down (fp8 weights, a 4-bit KV tier) in
+place of the served tokens: the check must then read false.  Benchmark
+runs never take it.
 
 Without a TPU (or with fewer chips than the cell asks for) the run
 fails and prints no result.  ``--rehearse`` is the one exception, for
@@ -55,10 +57,6 @@ WARM_GROUP = 2**31 - 1     # the set-up group's stream (window groups are 0, 1, 
 TRACED_CALL = 1            # the run() call that --trace 1 profiles
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-MODEL_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
-              "head_dim", "d_ff", "vocab_size", "mlp_activation",
-              "gated_mlp", "parallel_block", "rope_theta",
-              "tie_embeddings")
 
 
 class BenchError(RuntimeError):
@@ -92,7 +90,7 @@ class Run:
     requests: list[Served]
     stats: dict                     # ServeStats deltas over the window
     compiles: int                   # programs built inside the window
-    traced: dict | None = None      # {"window_s", "stats", "requests", "summary"}
+    traced: dict | None = None      # {"stats", "requests", "summary", "engine"}
 
 
 def parse(argv):
@@ -126,7 +124,7 @@ def model_config(config: dict, rehearse: bool):
     mcfg = base.replace(**m)
     if rehearse:
         mcfg = get_tiny(config["repo_config"])
-        m = {k: getattr(mcfg, k) for k in MODEL_KEYS}
+        m = {k: getattr(mcfg, k) for k in m}
     return mcfg, m
 
 
@@ -213,7 +211,7 @@ class Window:
         self.served: list[Served] = []
         self.calls = 0
         self.traced = None
-        self._t = None                  # (perf_counter, stats) of the traced span
+        self._s0 = None                 # stats as the traced call began
         self.t0 = time.perf_counter()
 
     def elapsed(self) -> float:
@@ -228,12 +226,12 @@ class Window:
                                   self.vocab, due, self.div)
 
     def _begin(self):
-        if self.trace and self.calls == TRACED_CALL and self._t is None:
+        if self.trace and self.calls == TRACED_CALL and self._s0 is None:
             import jax
 
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
             jax.profiler.start_trace(str(TRACE_DIR))
-            self._t = (time.perf_counter(), _stats(self.eng))
+            self._s0 = _stats(self.eng)
 
     def wait_until(self, due_s: float) -> None:
         self._begin()
@@ -256,11 +254,9 @@ class Window:
         with jax.profiler.TraceAnnotation("bench.engine_run"):
             got = serve(self.eng, reqs, self.calls, dues)
         self.served += got
-        if self._t is not None and self.traced is None:
+        if self._s0 is not None and self.traced is None:
             jax.profiler.stop_trace()
-            t0, s0 = self._t
-            self.traced = {"window_s": time.perf_counter() - t0,
-                           "stats": _delta(s0, _stats(self.eng)),
+            self.traced = {"stats": _delta(self._s0, _stats(self.eng)),
                            "requests": got}
         self.calls += 1
 
@@ -280,13 +276,12 @@ def sample(served: list[Served], k: int, seed: int) -> list[Served]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def check(params, m: dict, kv_bits: int, chosen, limit: float,
+def check(reference, params, m: dict, kv_bits: int, chosen, limit: float,
           control: bool = False) -> dict:
-    """Numbers compared, each beside its limit.  With ``control`` the
-    gaps are those of the tokens the control ranks first."""
+    """Numbers compared, each beside its limit, by ``reference`` (the
+    configuration's reference module).  With ``control`` the gaps are
+    those of the tokens the control ranks first."""
     import numpy as np
-
-    from bench import reference
 
     who = "control" if control else "program"
     worst = 0.0
@@ -298,6 +293,19 @@ def check(params, m: dict, kv_bits: int, chosen, limit: float,
         n_tok += len(g)
     return {"max_logit_gap": {"value": worst, "limit": limit},
             "tokens_compared": {"value": n_tok, "limit": None}}
+
+
+def traced_window(run) -> dict:
+    """``busy_s`` and ``window_s`` of the traced call: the device's op
+    time inside its ``serve.run`` span and the span's length, on the
+    trace's clock, so the profiler's start and stop are no part of it."""
+    from bench import engine_trace, readers
+
+    window_s = readers.traced_run_s(run)
+    if window_s is None:
+        raise BenchError("the trace holds no serve.run span of the engine")
+    busy_s = engine_trace.summary(run).busy_in_s(engine_trace.SPANS.RUN)
+    return {"busy_s": busy_s, "window_s": window_s}
 
 
 def device_block(devs) -> dict:
@@ -348,7 +356,7 @@ def main(argv=None, bench_json=None, bench_dir=None) -> int:
         from repro.sharding import rules
 
         shardings = rules.param_shardings(model.param_specs(mcfg), mesh)
-    params = weights.make_params(m, args.seed, shardings)
+    params = weights.make_params(cell.reference.layout(m), args.seed, shardings)
     weights.check_layout(params, model.abstract_params(mcfg))
     eng = ServeEngine(mcfg, params, mesh=mesh, **eng_kw)
     # set-up: one group of the deck builds every program the window runs
@@ -373,8 +381,6 @@ def main(argv=None, bench_json=None, bench_dir=None) -> int:
         from bench.tracing import read_trace
 
         traced["summary"] = read_trace(TRACE_DIR)
-        device["busy_s"] = traced["summary"].busy_s
-        device["window_s"] = traced["window_s"]
     del eng, win
     gc.collect()
 
@@ -382,6 +388,8 @@ def main(argv=None, bench_json=None, bench_dir=None) -> int:
               platform=devs[0].platform, peaks=pk, setup_s=setup_s,
               window_s=window_s, requests=served, stats=stats,
               compiles=counter.n, traced=traced)
+    if traced is not None:
+        device.update(traced_window(run))
     metrics = {}
     for met in (cell.per_layer if args.trace else cell.end_to_end):
         if args.rehearse and met.source == "device_trace":
@@ -393,9 +401,8 @@ def main(argv=None, bench_json=None, bench_dir=None) -> int:
     chk = config["check"]
     limit = chk["rehearse_max_logit_gap"] if args.rehearse else chk["max_logit_gap"]
     short = sum(len(s.tokens) != s.max_new for s in served)
-    checks = check(params, m, eng_kw["kv_frac_kbits"],
-                   sample(served, traffic["check_sample"], args.seed), limit,
-                   args.control)
+    checks = check(cell.reference, params, m, eng_kw["kv_frac_kbits"],
+                   sample(served, chk["sample"], args.seed), limit, args.control)
     checks["requests_short"] = {"value": short, "limit": 0}
     correct = all(c["limit"] is None or c["value"] <= c["limit"]
                   for c in checks.values())

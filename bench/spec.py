@@ -1,10 +1,13 @@
-"""Finds a cell's configuration, traffic mix and metric readers by name.
+"""Finds a cell's configuration, traffic mix, reference and metric
+readers by name.
 
 ``BENCHMARK.json`` names each cell's configuration and traffic; the
 files are ``<bench>/configs/<config>.json``, ``<bench>/traffic/
 <traffic>.json``, the loop the mix names, ``<bench>/loops/<loop>.py``,
-and ``<bench>/metrics/<metric>.py``.  Adding a configuration, a mix, a
-loop or a metric is adding files: nothing here lists them.
+the reference the configuration names (its ``"reference"`` key),
+``<bench>/references/<reference>.py``, and ``<bench>/metrics/
+<metric>.py``.  Adding a configuration, a mix, a loop, a reference or
+a metric is adding files: nothing here lists them.
 """
 from __future__ import annotations
 
@@ -13,6 +16,7 @@ import json
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -34,12 +38,20 @@ class Cell:
     config: dict
     traffic: dict
     drive: Callable           # drive(window), from the mix's loop
+    reference: ModuleType     # layout(m) and gaps(...), REFERENCE_API
     end_to_end: list[Metric] = field(default_factory=list)
     per_layer: list[Metric] = field(default_factory=list)
 
 
-def _function(bench_dir: Path, kind: str, name: str, fn: str) -> Callable:
-    """``fn`` of ``<bench_dir>/<kind>/<name>.py``."""
+# what a reference module defines:
+#   layout(m) -> {path: (shape, std)}, the weight tree (bench/weights.py)
+#   gaps(params, m, prompt, served, kv_bits, control=False)
+#       -> {"program": (n,) gaps[, "control": (n,) gaps]}
+REFERENCE_API = ("layout", "gaps")
+
+
+def _module(bench_dir: Path, kind: str, name: str, *fns: str) -> ModuleType:
+    """``<bench_dir>/<kind>/<name>.py``, which must define ``fns``."""
     path = bench_dir / kind / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"{kind} {name!r}: no file at {path}")
@@ -47,9 +59,15 @@ def _function(bench_dir: Path, kind: str, name: str, fn: str) -> Callable:
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    if not callable(getattr(mod, fn, None)):
-        raise AttributeError(f"{path} defines no {fn}()")
-    return getattr(mod, fn)
+    for fn in fns:
+        if not callable(getattr(mod, fn, None)):
+            raise AttributeError(f"{path} defines no {fn}()")
+    return mod
+
+
+def _function(bench_dir: Path, kind: str, name: str, fn: str) -> Callable:
+    """``fn`` of ``<bench_dir>/<kind>/<name>.py``."""
+    return getattr(_module(bench_dir, kind, name, fn), fn)
 
 
 def _load_json(path: Path, what: str) -> dict:
@@ -89,8 +107,13 @@ def load_cell(name: str, bench_json: Path | None = None,
         if d.get("name") != want:
             raise ValueError(f"{what} file names itself {d.get('name')!r}, "
                              f"not {want!r}")
+    if "reference" not in config:
+        raise KeyError(f"config {w['config']!r} names no reference "
+                       "(its \"reference\" key, a file in references/)")
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic,
                 drive=_function(bench_dir, "loops", traffic["loop"], "drive"),
+                reference=_module(bench_dir, "references", config["reference"],
+                                  *REFERENCE_API),
                 end_to_end=_metrics(spec["end_to_end"], name, bench_dir),
                 per_layer=_metrics(spec["per_layer"], name, bench_dir))

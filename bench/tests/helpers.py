@@ -12,12 +12,12 @@ FAST = {"rate_rps": 20.0,
 
 
 def bench_copy(tmp_path, **traffic_over):
-    """Copy BENCHMARK.json and bench/{configs,traffic,loops,metrics} into
+    """Copy BENCHMARK.json and bench/{configs,traffic,loops,references,metrics} into
     ``tmp_path``, each traffic file updated with ``traffic_over``.  Every
     configuration file gets a ``<config>.generate`` cell, with the
     per-layer metrics of the generate cells, so a configuration kept
     without a cell is still rehearsed."""
-    for sub in ("configs", "loops", "metrics"):
+    for sub in ("configs", "loops", "references", "metrics"):
         shutil.copytree(spec.BENCH_DIR / sub, tmp_path / sub)
     (tmp_path / "traffic").mkdir()
     for f in (spec.BENCH_DIR / "traffic").glob("*.json"):

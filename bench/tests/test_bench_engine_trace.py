@@ -1,6 +1,6 @@
 """Unit tests of ``bench/engine_trace.py``: the engine's spans and the
 decode loop's scopes read from look-alike profiler planes, and the
-three metrics that read them."""
+metrics and the result line's traced window that read them."""
 from types import SimpleNamespace as NS
 
 import pytest
@@ -65,9 +65,12 @@ def _hlo_ops():
             for instr, _, _, path in LOOP_OPS + [("while.1", 0, 0, "while")]}
 
 
+def _read_run(metric, run):
+    return spec._function(spec.BENCH_DIR, "metrics", metric, "read")(run)
+
+
 def _read(metric, engine):
-    read = spec._function(spec.BENCH_DIR, "metrics", metric, "read")
-    return read(NS(traced=None if engine is None else {"engine": engine}))
+    return _read_run(metric, NS(traced=None if engine is None else {"engine": engine}))
 
 
 def test_idle_share_run_ignores_the_profilers_stop():
@@ -81,6 +84,33 @@ def test_idle_share_run_ignores_the_profilers_stop():
     busy_s = tracing.reduce_planes(_span_planes()).busy_s
     assert busy_s == pytest.approx(500e-9)
     assert 100 * (1 - busy_s / 5000e-9) > 85
+
+
+def test_traced_window_and_mfu_read_the_serve_run_span():
+    """``device.window_s`` and ``mfu`` divide by ``serve.run`` [50, 750),
+    not by the traced window that runs on to the profiler's stop."""
+    from bench import counting, peaks, run
+
+    s = engine_trace.reduce_engine(_span_planes(), hlo_ops=_hlo_ops())
+    assert s.span_s("serve.run") == pytest.approx(700e-9)
+    assert s.busy_in_s("serve.run") == pytest.approx(500e-9)
+    assert s.span_s("serve.spill") is None and s.busy_in_s("serve.spill") is None
+    m = {"num_layers": 2, "d_model": 8, "num_heads": 4, "num_kv_heads": 2,
+         "head_dim": 2, "d_ff": 16, "vocab_size": 32, "gated_mlp": False}
+    reqs = [NS(prompt=[1] * 3, tokens=[1] * 4), NS(prompt=[1] * 5, tokens=[1] * 2)]
+    traced = NS(traced={"engine": s, "requests": reqs}, chips=1, model=m,
+                peaks=peaks.peaks("TPU v5 lite"))
+    assert run.traced_window(traced) == {"busy_s": pytest.approx(500e-9),
+                                         "window_s": pytest.approx(700e-9)}
+    flops = counting.request_flops(m, 3, 4) + counting.request_flops(m, 5, 2)
+    assert _read_run("mfu", traced) == pytest.approx(
+        100 * flops / (700e-9 * 197e12))
+    bare = NS(traced={"engine": engine_trace.reduce_engine(
+        _span_planes(with_run=False)), "requests": reqs}, chips=1, model=m,
+        peaks=traced.peaks)
+    assert _read_run("mfu", bare) is None
+    with pytest.raises(run.BenchError, match="serve.run"):
+        run.traced_window(bare)
 
 
 def test_span_gaps_named_by_the_engine_step_that_covers_them():

@@ -142,6 +142,45 @@ def test_open_loop_submits_whatever_is_due(capsys, tmp_path, monkeypatch):
     assert sizes[0] == 1 and len(sizes) >= 2
 
 
+EXTRA_LEAF = """
+
+_dense_layout = layout
+
+
+def layout(m):
+    out = _dense_layout(m)
+    out["layers/attn_0/bq"] = ((m["num_layers"], m["num_heads"], m["head_dim"]), 0.02)
+    return out
+"""
+
+
+def test_a_reference_is_a_new_file(capsys, tmp_path):
+    """A configuration that names a reference brought as a new file in
+    ``references/`` runs through ``bench/run.py`` with no other harness
+    file changed: a leaf the program lacks (a query bias) is named by
+    the layout check before anything is served, and without it the run
+    is correct."""
+    bj, bd = bench_copy(tmp_path, **FAST)
+    dense = (bd / "references" / "dense.py").read_text()
+    ref = bd / "references" / "dense_qbias.py"
+    ref.write_text(dense + EXTRA_LEAF)
+    cfg = json.loads((bd / "configs" / "stablelm-12b.pp4.json").read_text())
+    cfg.update(name="stablelm-qbias", reference="dense_qbias")
+    (bd / "configs" / "stablelm-qbias.json").write_text(json.dumps(cfg))
+    bench = json.loads(bj.read_text())
+    bench["workloads"].append({"name": "qbias.generate", "config": "stablelm-qbias",
+                               "traffic": "generate", "chips": 1, "why": "x"})
+    bj.write_text(json.dumps(bench))
+    assert spec.load_cell("qbias.generate", bj, bd).reference.__file__ == str(ref)
+    with pytest.raises(ValueError, match="layers/attn_0/bq"):
+        harness.main(["--workload", "qbias.generate", "--seed", "3",
+                      "--seconds", "1", "--rehearse"], bj, bd)
+    assert capsys.readouterr().out == ""
+    ref.write_text(dense)
+    line, _ = _run(capsys, tmp_path, "qbias.generate", 3, bench=(bj, bd))
+    assert line["correct"] is True and line["failed"] == 0
+
+
 MESH_RUN = """
 import sys
 from pathlib import Path

@@ -1,7 +1,9 @@
 """Unit tests of the benchmark's yardstick: trace reduction, FLOP and
 byte counts, the traffic generator, and loading cells by name."""
+import hashlib
 import json
 import math
+import re
 import shutil
 from types import SimpleNamespace as NS
 
@@ -170,12 +172,14 @@ def test_unknown_workload_raises():
 
 def test_cell_from_files_only_in_a_temporary_directory(tmp_path):
     """A new configuration, mix and metric are new files, nothing else."""
-    for sub in ("configs", "traffic", "loops", "metrics"):
+    for sub in ("configs", "traffic", "loops", "references", "metrics"):
         (tmp_path / sub).mkdir()
     shutil.copy(spec.BENCH_DIR / "configs" / "minitron-8b.pp4.json",
                 tmp_path / "configs" / "minitron-8b.pp4.json")
+    shutil.copy(spec.BENCH_DIR / "references" / "dense.py",
+                tmp_path / "references" / "dense.py")
     (tmp_path / "traffic" / "burst.json").write_text(json.dumps(
-        dict(CHAT, name="burst", loop="bursts", check_sample=2)))
+        dict(CHAT, name="burst", loop="bursts")))
     (tmp_path / "loops" / "bursts.py").write_text(
         "def drive(w):\n    w.call(w.requests(0))\n")
     (tmp_path / "metrics" / "requests_seen.py").write_text(
@@ -192,6 +196,8 @@ def test_cell_from_files_only_in_a_temporary_directory(tmp_path):
                        "layer": "l", "moves": "setup_s"}]}))
     cell = spec.load_cell("m.burst", tmp_path / "B.json", tmp_path)
     assert cell.traffic["name"] == "burst"
+    assert cell.reference.__file__ == str(tmp_path / "references" / "dense.py")
+    assert cell.config["check"]["sample"] == 16
     assert [m.name for m in cell.per_layer] == ["requests_seen"]
     assert cell.per_layer[0].read(NS(requests=[1, 2, 3])) == 3
     called = []
@@ -203,6 +209,53 @@ def test_unknown_loop_raises(tmp_path):
     bj, bd = bench_copy(tmp_path, loop="no-such-loop")
     with pytest.raises(FileNotFoundError, match="loops"):
         spec.load_cell("stablelm-12b.pp4.generate", bj, bd)
+
+
+def test_unknown_reference_raises_with_its_path(tmp_path):
+    bj, bd = bench_copy(tmp_path)
+    path = bd / "configs" / "stablelm-12b.pp4.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    reference="no-such-ref")))
+    want = str(bd / "references" / "no-such-ref.py")
+    with pytest.raises(FileNotFoundError, match=re.escape(want)):
+        spec.load_cell("stablelm-12b.pp4.generate", bj, bd)
+
+
+# the dense layout of stablelm-12b.pp4 as the harness drew it before the
+# reference named it: (path, shape, std), std None for a norm scale
+STABLELM_LAYOUT = [
+    ("embed", (100352, 5120), 1.0),
+    ("final_norm", (5120,), None),
+    ("layers/attn_0/wk", (10, 5120, 8, 160), 0.013975424859373685),
+    ("layers/attn_0/wo", (10, 32, 160, 5120), 0.0031249999999999997),
+    ("layers/attn_0/wq", (10, 5120, 32, 160), 0.013975424859373685),
+    ("layers/attn_0/wv", (10, 5120, 8, 160), 0.013975424859373685),
+    ("layers/mlp_0/w_down", (10, 13824, 5120), 0.0019018144357818268),
+    ("layers/mlp_0/w_gate", (10, 5120, 13824), 0.013975424859373685),
+    ("layers/mlp_0/w_up", (10, 5120, 13824), 0.013975424859373685),
+    ("layers/norm1_0", (10, 5120), None),
+    ("lm_head", (5120, 100352), 0.013975424859373685),
+]
+# sha256 over (path, bf16 bits) of each leaf, sorted, of the tiny preset's
+# weights at seed 4400000719 as the harness drew them before
+STABLELM_TINY_SHA256 = \
+    "b14f269de583e0ad147a83d2f767474ebf40ef6c9cf60a914fc3b5295326a6d8"
+
+
+def test_stablelm_dense_layout_and_weights_are_pinned():
+    from bench import run, weights
+
+    cell = spec.load_cell("stablelm-12b.pp4.generate")
+    assert cell.config["reference"] == "dense"
+    got = cell.reference.layout(cell.config["model"])
+    assert [(k, *got[k]) for k in sorted(got)] == STABLELM_LAYOUT
+    _, m = run.model_config(cell.config, True)
+    params = weights.make_params(cell.reference.layout(m), 4400000719)
+    h = hashlib.sha256()
+    for k, v in sorted(weights.flat(params).items()):
+        h.update(k.encode())
+        h.update(np.asarray(v).view(np.uint16).tobytes())
+    assert h.hexdigest() == STABLELM_TINY_SHA256
 
 
 def test_model_config_takes_corrected_keys_only_where_listed():
