@@ -26,6 +26,7 @@ _MODULES: dict[str, str] = {
     "pixtral-12b": "pixtral_12b",
     "rwkv6-1.6b": "rwkv6_1_6b",
     "whisper-medium": "whisper_medium",
+    "step3": "step3",
 }
 
 ARCH_IDS: tuple[str, ...] = tuple(_MODULES)

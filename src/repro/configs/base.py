@@ -31,12 +31,23 @@ class ModelConfig:
     num_experts: int = 0             # 0 = dense MLP
     experts_per_token: int = 0       # top-k
     moe_interleave: int = 1          # MoE every k-th layer (llama4: 2)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training's grouped routing only
     moe_group: int = 512             # routing group size (dispatch cost ∝ group)
+    moe_renorm: bool = True          # top-k gates renormalized to sum 1
+                                     # (step3: norm_expert_weight false)
+    shared_expert_ff: int = 0        # width of an always-on shared expert
+    # expert parallelism: this chip holds routed experts
+    # [experts_held_start, experts_held_start + experts_held) of the
+    # router's num_experts (0 = all of them) and computes their part
+    experts_held: int = 0
+    experts_held_start: int = 0
     # --- attention ------------------------------------------------------
     sliding_window: int = 0          # 0 = full attention
     rope_theta: float = 1_000_000.0
     attn_chunk: int = 1024           # query-chunked attention block size
+    q_lora_rank: int = 0             # low-rank query: D -> rank, RMSNorm,
+                                     # -> heads (step3's share_q_dim); 0 = one wq
+    norm_eps: float = 1e-6           # every RMSNorm of the decoder
     # --- mlp ------------------------------------------------------------
     mlp_activation: str = "silu"     # silu | gelu | relu2
     gated_mlp: bool = True
@@ -91,6 +102,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def n_held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def mamba_d_inner(self) -> int:

@@ -121,6 +121,17 @@ def activate(x: jax.Array, kind: str) -> jax.Array:
     raise ValueError(f"unknown activation {kind!r}")
 
 
+def mlp(x: jax.Array, mp: dict, cfg) -> jax.Array:
+    """The (optionally gated) MLP ``w_down(act(x @ w_gate) * x @ w_up)``
+    of ``cfg.mlp_activation``, in x.dtype."""
+    up = x @ mp["w_up"]
+    if cfg.gated_mlp:
+        h = activate(x @ mp["w_gate"], cfg.mlp_activation) * up
+    else:
+        h = activate(up, cfg.mlp_activation)
+    return h @ mp["w_down"]
+
+
 def sinusoidal_positions(seq: int, dim: int, offset=0) -> jax.Array:
     """Whisper-style sinusoidal embeddings; offset may be traced (decode)."""
     pos = jnp.arange(seq, dtype=jnp.float32) + offset

@@ -136,14 +136,14 @@ def supports_paged(cfg: ModelConfig) -> bool:
     """Whether the family serves through the paged KV pool.
 
     True for the attention families that already serve ragged buckets
-    with a full-length cache — their decode appends one KV row per step
-    at a per-sequence position, which maps 1:1 onto page-table writes.
-    False for state-space families (rwkv: O(1) state, nothing to page —
-    the engine falls back to the contiguous path), rolling (SWA)
-    windows (the rolling slot write crosses page boundaries
-    mid-stream), and the hybrid/audio/MoE families that cannot share a
-    ragged prefill (paged admission pre-stages requests through one
-    ragged prefill)."""
+    with a full-length cache, dense and MoE — their decode appends one
+    KV row per step at a per-sequence position, which maps 1:1 onto
+    page-table writes.  False for state-space families (rwkv: O(1)
+    state, nothing to page — the engine falls back to the contiguous
+    path), rolling (SWA) windows (the rolling slot write crosses page
+    boundaries mid-stream), and the hybrid/audio families that cannot
+    share a ragged prefill (paged admission pre-stages requests through
+    one ragged prefill)."""
     return supports_ragged(cfg) and cfg.family != "ssm"
 
 
@@ -157,18 +157,17 @@ def supports_ragged(cfg: ModelConfig) -> bool:
     """Whether mixed-length (right-padded) buckets serve with outputs
     bit-identical to solo serving.
 
-    True for pure-attention dense stacks with a full-length cache
-    (per-sequence valid masks hide pad slots) and for rwkv (prefill
-    freezes each lane's state at its own length).  False for rolling
-    (SWA) caches — the window emit is slot-aligned across the batch —
-    for hybrid/audio, whose mamba / encoder state emit has no per-lane
-    length masking, and for MoE: prefill routes with per-expert
-    capacity shared across the whole group, so pad tokens and bucket
-    neighbours can change which tokens drop (decode is dropless via
-    moe_block_decode, but prefill still couples lanes)."""
+    True for pure-attention stacks with a full-length cache, dense or
+    MoE (per-sequence valid masks hide pad slots; serving's expert
+    layer is dropless and per-token, moe.moe_layer, so no lane's
+    routing depends on another's) and for rwkv (prefill freezes each
+    lane's state at its own length).  False for rolling (SWA) caches —
+    the window emit is slot-aligned across the batch — and for
+    hybrid/audio, whose mamba / encoder state emit has no per-lane
+    length masking."""
     if cfg.family == "ssm":
         return True
-    if cfg.family in ("audio", "hybrid") or cfg.is_moe:
+    if cfg.family in ("audio", "hybrid"):
         return False
     return cfg.max_decode_window == 0
 

@@ -24,10 +24,10 @@ from repro.configs.base import ModelConfig
 from repro.models import mamba as mamba_mod
 from repro.models.common import (
     LeafSpec,
-    activate,
     apply_rope,
     attention,
     gather_pages,
+    mlp,
     rms_norm,
     stacked,
     windowed_prefill_attention,
@@ -70,12 +70,19 @@ def sublayer_kinds(cfg: ModelConfig) -> list[tuple[str, str]]:
 
 def attn_param_specs(cfg: ModelConfig) -> dict:
     D, H, K, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    return {
-        "wq": LeafSpec((D, H, hd), ("embed", "heads", "head_dim")),
+    specs = {
         "wk": LeafSpec((D, K, hd), ("embed", "kv_heads", "head_dim")),
         "wv": LeafSpec((D, K, hd), ("embed", "kv_heads", "head_dim")),
         "wo": LeafSpec((H, hd, D), ("heads", "head_dim", "embed")),
     }
+    r = cfg.q_lora_rank
+    if r:
+        specs["wq_a"] = LeafSpec((D, r), ("embed", "none"))
+        specs["q_norm"] = LeafSpec((r,), ("none",), init="ones")
+        specs["wq_b"] = LeafSpec((r, H, hd), ("none", "heads", "head_dim"))
+    else:
+        specs["wq"] = LeafSpec((D, H, hd), ("embed", "heads", "head_dim"))
+    return specs
 
 
 def mlp_param_specs(cfg: ModelConfig) -> dict:
@@ -133,8 +140,18 @@ def param_specs(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _query(x, ap, cfg):
+    """(B, S, D) -> (B, S, H, hd) before RoPE: one projection, or the
+    low-rank path (``wq_a``, RMSNorm, ``wq_b``) where the config has
+    ``q_lora_rank``."""
+    if "wq_a" not in ap:
+        return jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
+    qa = rms_norm(x @ ap["wq_a"], ap["q_norm"], cfg.norm_eps)
+    return jnp.einsum("bsr,rhk->bshk", qa, ap["wq_b"])
+
+
 def _qkv(x, ap, cfg, positions):
-    q = jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
+    q = _query(x, ap, cfg)
     k = jnp.einsum("bsd,dhk->bshk", x, ap["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, ap["wv"])
     q = apply_rope(q, positions, cfg.rope_theta)
@@ -185,7 +202,7 @@ def _attn_decode(x, ap, cfg: ModelConfig, cache, pos, kv_kbits=None):
     exactly the fidelity a k-bit cell array would return, same as the
     prefill rows (serve/engine.py's FRAC KV tier).
     """
-    q = jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
+    q = _query(x, ap, cfg)
     k = jnp.einsum("bsd,dhk->bshk", x, ap["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, ap["wv"])
     pos = jnp.asarray(pos)
@@ -253,7 +270,7 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pk, pv, layer, page_table,
     the numerics per slot, so parity survives FRAC).  Returns
     (out, pk, pv).
     """
-    q = jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
+    q = _query(x, ap, cfg)
     k = jnp.einsum("bsd,dhk->bshk", x, ap["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, ap["wv"])
     pos = jnp.asarray(pos)
@@ -300,25 +317,19 @@ def _attn_decode_paged(x, ap, cfg: ModelConfig, pk, pv, layer, page_table,
     return out, pk, pv
 
 
-def _mlp(x, mp, cfg: ModelConfig):
-    up = x @ mp["w_up"]
-    if cfg.gated_mlp:
-        h = activate(x @ mp["w_gate"], cfg.mlp_activation) * up
-    else:
-        h = activate(up, cfg.mlp_activation)
-    return h @ mp["w_down"]
-
-
-def _mix_mlp(x, bp, j, mlp_kind, cfg, decode=False):
-    from repro.models.moe import moe_block, moe_block_decode
+def _mix_mlp(x, bp, j, mlp_kind, cfg, serve=True, decode=False, live=None):
+    """The MLP or MoE sublayer: (out, held experts read).  Serving
+    (prefill and ``decode``) takes the dropless expert layer, training
+    the capacity-routed one (see moe.py); ``live`` marks the tokens
+    that serve a request: a prefill's real tokens (its pad slots take
+    no routed expert), the decode lanes whose routing counts as reads."""
+    from repro.models.moe import moe_block, moe_layer
 
     if mlp_kind == "moe":
-        if decode:
-            # dropless dense-combine path: same weights read, no
-            # capacity bookkeeping in the decode loop (see moe.py)
-            return moe_block_decode(x, bp[f"moe_{j}"], cfg)
-        return moe_block(x, bp[f"moe_{j}"], cfg)
-    return _mlp(x, bp[f"mlp_{j}"], cfg)
+        if serve:
+            return moe_layer(x, bp[f"moe_{j}"], cfg, live, decode)
+        return moe_block(x, bp[f"moe_{j}"], cfg), jnp.int32(0)
+    return mlp(x, bp[f"mlp_{j}"], cfg), jnp.int32(0)
 
 
 # ---------------------------------------------------------------------------
@@ -326,14 +337,15 @@ def _mix_mlp(x, bp, j, mlp_kind, cfg, decode=False):
 # ---------------------------------------------------------------------------
 
 
-def block_seq(x, bp, cfg: ModelConfig, *, emit_cache: bool):
-    """x: (B, S, D) through one period block; returns (x, cache|None)."""
+def block_seq(x, bp, cfg: ModelConfig, *, emit_cache: bool, live=None):
+    """x: (B, S, D) through one period block; returns (x, cache|None).
+    ``live`` (B, S) marks a ragged prefill's real tokens."""
     from repro.sharding.rules import shard_hint
 
     x = shard_hint(x, "batch", _seq_dim(cfg), "none")
     cache: dict[str, Any] = {}
     for j, (mixer, mlp_kind) in enumerate(sublayer_kinds(cfg)):
-        h = rms_norm(x, bp[f"norm1_{j}"])
+        h = rms_norm(x, bp[f"norm1_{j}"], cfg.norm_eps)
         if mixer == "attn":
             if emit_cache:
                 mixed, c = _attn_seq(x=h, ap=bp[f"attn_{j}"], cfg=cfg, emit_cache=True)
@@ -345,12 +357,15 @@ def block_seq(x, bp, cfg: ModelConfig, *, emit_cache: bool):
             if emit_cache:
                 st = mamba_prefill_state(h, bp[f"mamba_{j}"], cfg)
                 cache[f"mconv_{j}"], cache[f"mssm_{j}"] = st["conv"], st["ssm"]
+        # emit_cache is serving's prefill: the dropless expert layer
         if cfg.parallel_block:
-            x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg)
+            x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg, emit_cache,
+                                     live=live)[0]
         else:
             x = x + mixed
-            h2 = rms_norm(x, bp[f"norm2_{j}"])
-            x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg)
+            h2 = rms_norm(x, bp[f"norm2_{j}"], cfg.norm_eps)
+            x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, emit_cache,
+                             live=live)[0]
     return x, (cache if emit_cache else None)
 
 
@@ -384,7 +399,7 @@ def block_decode(x, bp, bc, cfg: ModelConfig, pos, kv_kbits=None):
     """One token through one period block.  x: (B, 1, D)."""
     new_cache: dict[str, Any] = {}
     for j, (mixer, mlp_kind) in enumerate(sublayer_kinds(cfg)):
-        h = rms_norm(x, bp[f"norm1_{j}"])
+        h = rms_norm(x, bp[f"norm1_{j}"], cfg.norm_eps)
         if mixer == "attn":
             mixed, c = _attn_decode(
                 h, bp[f"attn_{j}"], cfg, {"k": bc[f"k_{j}"], "v": bc[f"v_{j}"]},
@@ -397,12 +412,18 @@ def block_decode(x, bp, bc, cfg: ModelConfig, pos, kv_kbits=None):
             mixed = out2d[:, None, :]
             new_cache[f"mconv_{j}"], new_cache[f"mssm_{j}"] = st["conv"], st["ssm"]
         if cfg.parallel_block:
-            x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg, decode=True)
+            x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg, decode=True)[0]
         else:
             x = x + mixed
-            h2 = rms_norm(x, bp[f"norm2_{j}"])
-            x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)
+            h2 = rms_norm(x, bp[f"norm2_{j}"], cfg.norm_eps)
+            x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)[0]
     return x, new_cache
+
+
+# key of the int32 counter the paged decode loop may carry among the
+# pools (build_paged_decode_loop): held experts read, summed over steps
+# and MoE layers
+EXPERT_READS = "expert_reads"
 
 
 def block_decode_paged(x, bp, pool, layer, cfg: ModelConfig, page_table,
@@ -410,24 +431,31 @@ def block_decode_paged(x, bp, pool, layer, cfg: ModelConfig, page_table,
                        paged_kernel=False):
     """One token through period block ``layer`` against the stacked
     paged pools, which come back with this block's rows written.
-    Only pure-attention blocks page (model.supports_paged)."""
+    Only pure-attention blocks page (model.supports_paged).  Where the
+    pools carry an ``EXPERT_READS`` counter, each MoE sublayer adds the
+    held experts that the ``write_mask`` lanes routed to."""
     pool = dict(pool)
     for j, (mixer, mlp_kind) in enumerate(sublayer_kinds(cfg)):
         assert mixer == "attn", "paged decode is attention-only"
         with jax.named_scope(spans.ATTN):
-            h = rms_norm(x, bp[f"norm1_{j}"])
+            h = rms_norm(x, bp[f"norm1_{j}"], cfg.norm_eps)
             mixed, pool[f"k_{j}"], pool[f"v_{j}"] = _attn_decode_paged(
                 h, bp[f"attn_{j}"], cfg, pool[f"k_{j}"], pool[f"v_{j}"],
                 layer, page_table, pos, kv_kbits, write_mask, paged_kernel,
             )
-        with jax.named_scope(spans.MLP):
+        with jax.named_scope(spans.MOE if mlp_kind == "moe" else spans.MLP):
             if cfg.parallel_block:
-                x = x + mixed + _mix_mlp(h, bp, j, mlp_kind, cfg,
-                                         decode=True)
+                y, reads = _mix_mlp(h, bp, j, mlp_kind, cfg, decode=True,
+                                    live=write_mask)
+                x = x + mixed + y
             else:
                 x = x + mixed
-                h2 = rms_norm(x, bp[f"norm2_{j}"])
-                x = x + _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True)
+                h2 = rms_norm(x, bp[f"norm2_{j}"], cfg.norm_eps)
+                y, reads = _mix_mlp(h2, bp, j, mlp_kind, cfg, decode=True,
+                                    live=write_mask)
+                x = x + y
+        if mlp_kind == "moe" and EXPERT_READS in pool:
+            pool[EXPERT_READS] = pool[EXPERT_READS] + reads
     return x, pool
 
 
@@ -494,7 +522,7 @@ def forward(cfg: ModelConfig, params, batch) -> jax.Array:
         return x, None
 
     x, _ = _scan_blocks(cfg, params, x, body)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _lm_head(cfg, params, x)
 
 
@@ -504,18 +532,21 @@ def prefill(cfg: ModelConfig, params, batch, lengths=None):
     every real token's activations bit-identical to an unpadded run,
     and the returned logits are each sequence's own last *real* token
     (index ``lengths - 1``).  Pad-slot cache rows are garbage — the
-    ragged decode path masks them out via per-sequence valid lengths."""
+    ragged decode path masks them out via per-sequence valid lengths —
+    and pad slots take no routed expert."""
     x = _embed_in(cfg, params, batch)
+    live = (None if lengths is None else
+            jnp.arange(x.shape[1])[None, :] < lengths[:, None])
 
     def body(x, bp):
-        return block_seq(x, bp, cfg, emit_cache=True)
+        return block_seq(x, bp, cfg, emit_cache=True, live=live)
 
     x, cache = _scan_blocks(cfg, params, x, body)
     if lengths is None:
         x = x[:, -1:]
     else:
         x = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _lm_head(cfg, params, x), cache
 
 
@@ -533,7 +564,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, kv_kbits=None):
     if cfg.remat == "full":
         pass  # no grads in decode; remat irrelevant
     x, new_cache = lax.scan(body, x, (params["layers"], cache))
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _lm_head(cfg, params, x)[:, 0], new_cache
 
 
@@ -550,7 +581,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool, page_table, tokens,
     into the carried stack and reads its pages from it in place, so a
     step never slices, copies or rebuilds the pool.  ``paged_kernel``
     reads through the fused page-walk kernel instead of the gather
-    oracle (see kernels/paged_attn).  Returns (logits, pool)."""
+    oracle (see kernels/paged_attn).  An ``EXPERT_READS`` counter among
+    the pools rides the same carry (block_decode_paged).  Returns
+    (logits, pool)."""
     x = params["embed"][tokens][:, None, :]                 # (B, 1, D)
 
     def body(carry, bp):
@@ -563,7 +596,7 @@ def decode_step_paged(cfg: ModelConfig, params, pool, page_table, tokens,
     (x, pool, _), _ = lax.scan(
         body, (x, pool, jnp.asarray(0, jnp.int32)), params["layers"])
     with jax.named_scope(spans.HEAD):
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return _lm_head(cfg, params, x)[:, 0], pool
 
 

@@ -16,9 +16,8 @@ the bucket max and share one prefill; per-sequence positions / valid
 lengths are threaded through ``model.decode_step`` so each lane writes
 its own cache slot and masks its own span.  Outputs are bit-identical
 to serving each request alone (greedy; locked by tests).  Families
-with rolling (SWA) windows, unfrozen state emit (hybrid/audio) or
-group-coupled prefill routing (MoE capacity) fall back to exact-length
-buckets.  Admission is slot-based: each bucket
+with rolling (SWA) windows or unfrozen state emit (hybrid/audio) fall
+back to exact-length buckets.  Admission is slot-based: each bucket
 fills up to ``max_batch`` slots from the pending queue at bucket
 boundaries, completed requests drain into a results map, so sustained
 load stays O(pending).
@@ -52,9 +51,11 @@ max_new) its pages return to a device-side free list and the next
 staged request takes the lane without leaving the ``while_loop`` —
 one host sync serves the whole super-bucket.  Outputs stay
 bit-identical to the contiguous engine and to solo serving (locked by
-tests/test_serve_paged.py).  Families that don't page (rwkv's O(1)
-state, SWA, MoE/hybrid/audio) silently fall back to the contiguous
-path.
+tests/test_serve_paged.py).  MoE stacks page like dense ones (their
+expert layer is dropless, models/moe.py); ``stats.expert_reads`` counts
+the held experts that live lanes routed to.  Families that don't page
+(rwkv's O(1) state, SWA, hybrid/audio) silently fall back to the
+contiguous path.
 
 An optional ``mesh`` shards params (weight rule), caches (decode-cache
 rule, which also places the paged pool) and the loop's per-sequence
@@ -103,6 +104,7 @@ from repro.core.ese.meter import MeterConfig, SustainabilityMeter
 from repro.core.ese.records import EnergyReport
 from repro.models import model
 from repro.models.common import greedy_sample, is_leaf_spec
+from repro.models.transformer import EXPERT_READS
 from repro.serve import paging
 
 
@@ -141,6 +143,9 @@ class ServeStats:
                                     # (paged: the pow2-rounded pool)
     kv_pages_peak: int = 0          # paged: max pages live at once
     admissions: int = 0             # paged: in-loop slot refills
+    expert_reads: int = 0           # paged MoE: held experts at least one
+                                    # live lane routed to, summed over
+                                    # decode steps and MoE layers
     attn_transient_peak: int = 0    # paged: modeled peak per-layer
                                     # attention-read transient bytes per
                                     # decode step (gather pays the
@@ -231,8 +236,12 @@ def build_paged_decode_loop(mcfg: ModelConfig, *, eos_id: int | None = None,
     Returns ``loop(params, pool, page_table, free_stack, free_top,
     tok0, pos0, staged_tok0, staged_len, staged_pt, max_new) ->
     (out (R, out_cap), n_out (R,), steps, pages_peak,
-    pages_per_req (R,), admissions, final pool)`` where ``R = B + Q``
-    requests (B decode lanes + Q pre-staged).  The pool is donated.
+    pages_per_req (R,), admissions, expert_reads, final pool)`` where
+    ``R = B + Q`` requests (B decode lanes + Q pre-staged).  The pool
+    is donated.  For an MoE stack the carried pools hold one more
+    leaf, the ``transformer.EXPERT_READS`` counter, which every step's
+    MoE layers add to; ``expert_reads`` is its final value (0 for a
+    dense stack).
 
     The carry holds, besides the contiguous loop's vectors, the page
     table, the free-list stack, a lane→request map and the page
@@ -271,6 +280,8 @@ def build_paged_decode_loop(mcfg: ModelConfig, *, eos_id: int | None = None,
             jnp.zeros((1,), jnp.int32),
         ])
         in_use = ppr.sum()
+        if mcfg.is_moe:
+            pool = dict(pool, **{EXPERT_READS: jnp.asarray(0, jnp.int32)})
         c = dict(pool=pool, pt=page_table, fs=free_stack,
                  ft=jnp.asarray(free_top, jnp.int32), tok=tok0, pos=pos0,
                  alive=alive, lane=rows_b.astype(jnp.int32), out=out,
@@ -363,8 +374,10 @@ def build_paged_decode_loop(mcfg: ModelConfig, *, eos_id: int | None = None,
         # cond() check, or a bucket of max_new=1 requests with a full
         # stage queue would exit immediately
         c = jax.lax.while_loop(cond, body, maintain(c))
+        pool = dict(c["pool"])
+        reads = pool.pop(EXPERT_READS, jnp.asarray(0, jnp.int32))
         return (c["out"][:R], c["n_out"][:R], c["steps"], c["peak"],
-                c["ppr"][:R], c["adm"], c["pool"])
+                c["ppr"][:R], c["adm"], reads, pool)
 
     return jax.jit(loop, donate_argnums=(1,))
 
@@ -792,12 +805,12 @@ class ServeEngine:
             self.stats.ttft_s.append(t_first - r.t_submit)
         with TraceAnnotation(spans.LOOP, out_cap=out_cap):
             loop = self._get_paged_loop(out_cap)
-            out, n_out, steps, peak, ppr, adm, _ = loop(
+            out, n_out, steps, peak, ppr, adm, reads, _ = loop(
                 self.params, pool, pt, fs, np.int32(plan.free_top),
                 tok0[:nb], pos0, tok0[nb:], slen, spt, mn)
             # the super-bucket's single host transfer
-            out_np, n_np, steps_np, peak_np, ppr_np, adm_np = \
-                jax.device_get((out, n_out, steps, peak, ppr, adm))
+            out_np, n_np, steps_np, peak_np, ppr_np, adm_np, reads_np = \
+                jax.device_get((out, n_out, steps, peak, ppr, adm, reads))
         with TraceAnnotation(spans.FINISH, steps=int(steps_np)):
             self.stats.host_syncs += 1
             now = time.time()
@@ -805,6 +818,7 @@ class ServeEngine:
             self._note_steps(now - t_first, int(steps_np))
             self.stats.decode_s += now - t_first
             self.stats.admissions += int(adm_np)
+            self.stats.expert_reads += int(reads_np)
             assert int(adm_np) == staged_n, "stage queue not drained in-loop"
             self._book_pages(nb, plan, peak_np, ppr_np, reqs, out_np, n_np,
                              now, now - t_bucket0)
@@ -1049,14 +1063,14 @@ class ServeEngine:
                 pool_specs, cache_w, is_leaf=is_leaf_spec)
         with TraceAnnotation(spans.LOOP, out_cap=out_cap):
             loop = self._get_paged_loop(out_cap)
-            out, n_out, steps, peak, ppr, adm, _ = loop(
+            out, n_out, steps, peak, ppr, adm, reads, _ = loop(
                 self.params, pool, jnp.asarray(plan.page_table),
                 jnp.asarray(plan.free_stack), np.int32(plan.free_top),
                 tok0, jnp.asarray(lens), jnp.zeros((0,), jnp.int32),
                 jnp.zeros((0,), jnp.int32), jnp.asarray(plan.staged_pt),
                 jnp.asarray(max_new))
-            out_np, n_np, steps_np, peak_np, ppr_np, adm_np = \
-                jax.device_get((out, n_out, steps, peak, ppr, adm))
+            out_np, n_np, steps_np, peak_np, ppr_np, adm_np, reads_np = \
+                jax.device_get((out, n_out, steps, peak, ppr, adm, reads))
         with TraceAnnotation(spans.FINISH, steps=int(steps_np)):
             self.stats.host_syncs += 1
             now = time.time()
@@ -1064,6 +1078,7 @@ class ServeEngine:
             self._note_steps(now - t_wave0, int(steps_np))
             self.stats.decode_s += now - t_wave0
             assert int(adm_np) == 0
+            self.stats.expert_reads += int(reads_np)
             self.stats.oversub_waves += 1
             self._book_pages(len(wreqs), plan, peak_np, ppr_np, wreqs,
                              out_np, n_np, now, now - t_wave0)

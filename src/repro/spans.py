@@ -10,6 +10,10 @@ and cost a few hundred ns when no profiler runs.  ``RUN`` covers one
 Device scopes are ``jax.named_scope``s: HLO ``op_name`` metadata only,
 no runtime cost.  ``ATTN`` holds two child scopes, so an op under them
 carries the path ``decode.attn/kv_write`` or ``decode.attn/attn_read``.
+An MoE layer's decode sits under ``MOE`` in place of ``MLP``, with the
+children ``route`` (router, top-k, the held-expert count), ``experts``
+(the held experts' matmuls and the gated combine) and ``shared`` (the
+shared expert).
 The ``lax.scan`` over layers and the decode ``while_loop``'s carry are
 left unscoped on purpose: what XLA adds to move the stacked KV pool
 (slices, relayouts, carry copies) is the loop's unscoped time.
@@ -32,6 +36,10 @@ ATTN = "decode.attn"                 # norm, QKV, rope, output projection
 KV_WRITE = "kv_write"                # FRAC fake-quant + the page write
 ATTN_READ = "attn_read"              # the paged kernel or the gather read
 MLP = "decode.mlp"
+MOE = "decode.moe"                   # an MoE layer's norm and expert layer
+MOE_ROUTE = "route"                  # router, top-k, held experts read
+MOE_EXPERTS = "experts"              # held experts' matmuls, gated combine
+MOE_SHARED = "shared"                # the shared expert
 HEAD = "decode.head"                 # final norm, LM head, greedy sample
 LOOP_ALLOC = "loop.alloc"            # on-demand page allocation
 LOOP_EMIT = "loop.emit"              # tokens into request rows, lane liveness
@@ -39,6 +47,7 @@ LOOP_ADMIT = "loop.admit"            # free dead lanes' pages, admit staged requ
 
 KV_WRITE_PATH = f"{ATTN}/{KV_WRITE}"
 ATTN_READ_PATH = f"{ATTN}/{ATTN_READ}"
+MOE_PATHS = tuple(f"{MOE}/{c}" for c in (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED))
 
 DEVICE = (ATTN, KV_WRITE_PATH, ATTN_READ_PATH, MLP, HEAD,
-          LOOP_ALLOC, LOOP_EMIT, LOOP_ADMIT)
+          LOOP_ALLOC, LOOP_EMIT, LOOP_ADMIT, MOE, *MOE_PATHS)

@@ -70,9 +70,11 @@ def test_decode_matches_forward(arch):
 
 
 def test_moe_decode_matches_forward_loosely():
-    """MoE capacity dropping differs between a 16-token prefill group and
-    a 1-token decode group, so only check the argmax token agrees most
-    of the time (top-k routing itself is deterministic)."""
+    """Serving's expert layer (prefill and decode) is dropless; the
+    training forward routes with per-group capacity, set high enough
+    here (4.0) that nothing drops.  The two compute the same experts
+    with different grouping and rounding (fp32 accumulation against
+    the capacity path's bf16 einsums), so the logits agree loosely."""
     cfg = get_tiny("mixtral-8x7b").replace(sliding_window=0, max_decode_window=0,
                                            capacity_factor=4.0)
     params = model.init_params(cfg, jax.random.PRNGKey(1))

@@ -80,6 +80,9 @@ def test_full_config_param_counts(arch):
         "pixtral-12b": (12.3e9, 0.05),
         "rwkv6-1.6b": (1.6e9, 0.05),
         "whisper-medium": (0.77e9, 0.05),
+        # 321B published: the vision tower and the five dense layers
+        # (0-3, 60) are not in the repo's uniform MoE stack
+        "step3": (321e9, 0.03),
     }[arch]
     cfg = get_config(arch)
     n = model.count_params(cfg)

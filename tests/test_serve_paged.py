@@ -407,14 +407,16 @@ def test_paged_solo_degenerates_to_single_lane():
 # ---------------------------------------------------------------------------
 
 
-def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32, L=3):
+def _rand_paged_fixture(seed, B, ps, dtype=jnp.float32, L=3,
+                        heads=(4, 2, 8)):
     """Random stacked pools ``(L, P, ps, K*hd)`` (every layer its own
     contents) + valid page tables + ragged positions: every lane's
     allocated prefix covers its own ``pos`` (the invariant the engine's
-    allocator maintains), page ids distinct across lanes, -1 tails."""
+    allocator maintains), page ids distinct across lanes, -1 tails.
+    ``heads`` is (query heads, KV heads, head_dim)."""
     rng = np.random.default_rng(seed)
     mp = int(rng.integers(2, 6))
-    H, K, hd = 4, 2, 8
+    H, K, hd = heads
     P = B * mp + 1                               # + trash page 0
     q = jnp.asarray(rng.standard_normal((B, H, hd)), dtype)
     pk = jnp.asarray(rng.standard_normal((L, P, ps, K * hd)), dtype)
@@ -462,6 +464,24 @@ def test_paged_attn_modes_agree_and_match_oracle():
                                rtol=1e-5, atol=1e-6)
     with pytest.raises(ValueError, match="expected one of"):
         pops.paged_attention(q, pk, pv, table, pos, 1, mode="cuda")
+
+
+def test_paged_attn_step3_geometry_bit_equal():
+    """Step-3's geometry, one KV head under 64 query heads of 256, in
+    the served dtype: the kernel in interpret mode is bit-equal to the
+    jnp walk, and both agree with the gather oracle to bf16 rounding."""
+    from repro.kernels.paged_attn import ops as pops
+
+    q, pk, pv, table, pos = _rand_paged_fixture(
+        3, B=3, ps=16, dtype=jnp.bfloat16, L=2, heads=(64, 1, 256))
+    o_jnp = pops.paged_attention(q, pk, pv, table, pos, 1, mode="jnp")
+    o_int = pops.paged_attention(q, pk, pv, table, pos, 1,
+                                 mode="pallas_interpret")
+    assert o_jnp.shape == (3, 64, 256) and jnp.array_equal(o_jnp, o_int)
+    oracle = _oracle_attn(q, pk, pv, table, pos, 1)
+    np.testing.assert_allclose(np.asarray(o_jnp, np.float32),
+                               np.asarray(oracle, np.float32),
+                               rtol=0.02, atol=0.02)
 
 
 @pytest.mark.parametrize("mode", ["jnp", "pallas_interpret"])

@@ -96,7 +96,9 @@ def test_trace_holds_the_loops_hlo_with_its_scopes(traced):
     raw = open(tracing.find_xplane(logdir), "rb").read()
     names = engine_trace.hlo_op_names(raw)
     found = {engine_trace.scope_of(p, spans.DEVICE) for p in names.values()}
-    assert set(spans.DEVICE) <= found
+    # a dense stack has no expert layer: every scope but the MoE's
+    moe = {spans.MOE, *spans.MOE_PATHS}
+    assert set(spans.DEVICE) - moe <= found and not moe & found
     assert "" in found                 # XLA's own instructions carry none
 
 
@@ -152,3 +154,87 @@ def test_lowered_paged_loop_carries_the_decode_scopes(kv_kbits):
                   spans.HEAD, spans.LOOP_ALLOC, spans.LOOP_EMIT,
                   spans.LOOP_ADMIT):
         assert scope in text, scope
+
+
+MOE_ARCH = "step3"
+
+
+def _moe_loop(cfg, B=3, Q=0, mp=4, P=16, ps=4, kv_kbits=8):
+    pool = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
+                        model.paged_pool_specs(cfg, P, ps),
+                        is_leaf=is_leaf_spec)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    loop = build_paged_decode_loop(cfg, kv_kbits=kv_kbits, out_cap=8,
+                                   page_size=ps)
+    return loop.lower(model.abstract_params(cfg), pool, i32(B, mp), i32(P),
+                      i32(), i32(B), i32(B), i32(Q), i32(Q), i32(Q, mp),
+                      i32(B + Q))
+
+
+def test_moe_ops_sit_under_decode_moe_and_its_children():
+    """In the compiled MoE loop the router and its top-k are under
+    ``decode.moe/route``, the held experts' batched matmuls under
+    ``decode.moe/experts``, the shared expert's matmuls under
+    ``decode.moe/shared``, and no op is under ``decode.mlp``: the trace
+    reads the expert layer as its own, never as another sublayer or as
+    the loop's overhead.  Decode takes the dense form: nothing in the
+    loop sorts by expert."""
+    import re
+
+    hlo = _moe_loop(get_tiny(MOE_ARCH)).compile().as_text()
+    by_scope: dict = {}
+    for m in re.finditer(r" = \S+ ([\w\-]+)\(.*op_name=\"([^\"]*)\"", hlo):
+        opcode, path = m.groups()
+        by_scope.setdefault(engine_trace.scope_of(path, spans.DEVICE),
+                            []).append((opcode, path))
+    assert {spans.MOE, *spans.MOE_PATHS} <= set(by_scope)
+    assert spans.MLP not in by_scope
+    route, experts, shared = (by_scope[p] for p in spans.MOE_PATHS)
+    assert any("/top_k" in path for _, path in route)
+    assert any(op == "dot" for op, _ in route)
+    assert any(op == "dot" for op, _ in experts)
+    assert any(op == "dot" for op, _ in shared)
+    for scope, members in by_scope.items():
+        assert not any("argsort" in p for _, p in members), scope
+        if scope != f"{spans.MOE}/{spans.MOE_ROUTE}":
+            assert not any("top_k" in p for _, p in members), scope
+
+
+def test_expert_reads_equal_a_host_recount_of_the_routing(monkeypatch):
+    """``ServeStats.expert_reads``, carried in the loop and fetched with
+    its single transfer, equals a host recount: each decode step and
+    MoE layer, the held experts that at least one live lane chose."""
+    from repro.models import moe
+
+    cfg = get_tiny(MOE_ARCH)
+    params = model.init_params(cfg, jax.random.PRNGKey(4))
+    seen = []
+    real = moe.route
+
+    def spy(x, router, c):
+        gate, idx = real(x, router, c)
+        jax.debug.callback(lambda i: seen.append(np.asarray(i)), idx)
+        return gate, idx
+
+    monkeypatch.setattr(moe, "route", spy)
+    prompts, max_new = PROMPTS[:3], [6, 9, 4]
+    # three lanes, nothing staged: lane b serves request b, live while
+    # it has tokens left to decode
+    eng = ServeEngine(cfg, params, max_batch=3, paged=True, page_size=4,
+                      stage_depth=0, kv_frac_kbits=8)
+    for p, n in zip(prompts, max_new):
+        eng.submit(p, max_new_tokens=n)
+    eng.run()
+    decode = [i for i in seen if i.shape[0] == len(prompts)]
+    L = cfg.num_layers
+    assert len(decode) == L * eng.stats.decode_steps == L * (max(max_new) - 1)
+    lo, hi = cfg.experts_held_start, cfg.experts_held_start + cfg.n_held_experts
+    want = 0
+    for r, idx in enumerate(decode):
+        step = r // L
+        live = [b for b, n in enumerate(max_new) if step < n - 1]
+        want += len({int(e) for b in live for e in idx[b] if lo <= e < hi})
+    assert want > 0 and eng.stats.expert_reads == want

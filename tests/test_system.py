@@ -344,11 +344,11 @@ def test_shapes_registry_complete():
     from repro.configs import ARCH_IDS, get_config, shape_applicable
 
     cells = [(a, s) for a in ARCH_IDS for s in SHAPES]
-    assert len(cells) == 40            # the assigned 40-cell grid
+    assert len(cells) == 44            # the assigned 40-cell grid + step3
     runnable = [c for c in cells
                 if shape_applicable(get_config(c[0]), SHAPES[c[1]])]
-    # 7 full-attention archs skip long_500k
-    assert len(runnable) == 40 - 7
+    # 8 full-attention archs (step3 among them) skip long_500k
+    assert len(runnable) == 44 - 8
 
 
 def test_amoeba_engine_dispatch():

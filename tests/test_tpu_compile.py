@@ -80,6 +80,23 @@ def test_paged_attention_llama3_2_3b(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
+def test_paged_attention_step3(one_chip):
+    """Step-3's decode attention as the benchmark serves it: one KV head
+    under 64 query heads of 256, 8 lanes, 4 stacked layers."""
+    cfg = get_config("step3")
+    B, P, ps, max_pages = 8, 1024, 16, 128
+    H, K, hd, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, 4
+    assert (H, K, hd) == (64, 1, 256)
+    compiled = _compile(
+        lambda q, pk, pv, pt, pos, layer: paged_ops.paged_attention(
+            q, pk, pv, pt, pos, layer, mode="pallas"),
+        one_chip,
+        ((B, H, hd), jnp.bfloat16), ((L, P, ps, K * hd), jnp.bfloat16),
+        ((L, P, ps, K * hd), jnp.bfloat16), ((B, max_pages), jnp.int32),
+        ((B,), jnp.int32), ((), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < P * ps * K * hd * 2
+
+
 def test_paged_decode_loop_stablelm_12b_moves_no_pool(one_chip,
                                                       monkeypatch):
     """The benchmark's stablelm-12b stage (10 layers at published
